@@ -38,8 +38,8 @@ perf PR diffs against.  Sections:
   payload each compiled decode step moves, read off the optimized HLO),
   plus the disaggregated prefill/decode hand-off: per-migration bytes
   fp-vs-vq costed through ``core.comm_model`` at 10/100/500 Mbps.  On a
-  single-device host the mesh collapses to one shard and the disagg
-  groups overlap, so the rows land in CI regardless of topology.
+  single-device host the mesh collapses to one shard and the disagg rows
+  are left out (the prefill and decode groups need a device each).
 * compile counts (CountingJit traces) and host syncs for every engine run.
 * **traffic** (written by ``benchmarks/traffic_bench.py``, merged into the
   same report): SLA numbers from seeded Poisson/bursty arrival traces
@@ -446,7 +446,8 @@ def bench_mesh(cfg, params, *, arch, max_len, prompt_lens, max_new,
 
     half = max(num_shards // 2, 1)
     migration = {}
-    for mode in migrate_modes:
+    # disjoint prefill / decode groups need two devices at least
+    for mode in (migrate_modes if n >= 2 else ()):
         if mode == "vq":  # vq layouts need the astra codebooks in params
             mcfg = get_config(arch).reduced()
             mparams = mf.init_params(jax.random.PRNGKey(0), mcfg)

@@ -1,100 +1,50 @@
-"""Version-adaptive JAX API surface.
+"""The JAX API seams the SPMD stack goes through.
 
-The SPMD stack targets three JAX API seams that moved between 0.4.x and
-0.6+:
+Every module under ``repro/`` calls the wrappers here instead of touching
+these APIs directly (rule ``compat-api`` in ``repro.analysis``, asserted by
+``tests/test_compat.py``), so a future JAX upgrade that moves one of them
+changes this file only:
 
-  * ``shard_map`` graduated from ``jax.experimental.shard_map`` to
-    ``jax.shard_map``, renaming ``check_rep`` -> ``check_vma`` on the way;
-  * ``jax.make_mesh`` grew an ``axis_types=`` kwarg (and
-    ``jax.sharding.AxisType`` itself) only in newer releases;
-  * ``Compiled.cost_analysis()`` returns a flat dict on new JAX but a
-    list of per-program dicts on 0.4.x.
+  * ``jax.shard_map`` and its ``check_vma`` kwarg;
+  * ``jax.lax.axis_size``;
+  * ``jax.make_mesh`` with ``jax.sharding.AxisType`` axis types;
+  * ``Compiled.cost_analysis()``, normalized to one flat dict.
 
-Every module under ``repro/`` goes through the wrappers here instead of
-touching those APIs directly (enforced by ``tests/test_compat.py``), so the
-pinned runtime and future upgrades both stay green.
+The repository targets the JAX pinned in ``requirements-dev.txt``; there
+is no branch for any other version.
 """
 from __future__ import annotations
 
-from typing import Any, Callable, Dict, Optional, Sequence, Tuple
+from typing import Any, Callable, Dict, Optional, Sequence
 
 import jax
 
-JAX_VERSION: Tuple[int, ...] = tuple(
-    int(p) for p in jax.__version__.split(".")[:3] if p.isdigit())
 
-
-# ---------------------------------------------------------------------------
-# shard_map
-# ---------------------------------------------------------------------------
-
-if hasattr(jax, "shard_map"):  # jax >= 0.6: top-level, check_vma kwarg
-
-    def shard_map(f: Callable, *, mesh, in_specs, out_specs,
-                  check_vma: bool = True) -> Callable:
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma)
-
-else:  # jax 0.4.x / 0.5.x: experimental, check_rep kwarg
-    from jax.experimental.shard_map import shard_map as _shard_map_impl
-
-    def shard_map(f: Callable, *, mesh, in_specs, out_specs,
-                  check_vma: bool = True) -> Callable:
-        return _shard_map_impl(f, mesh=mesh, in_specs=in_specs,
-                               out_specs=out_specs, check_rep=check_vma)
+def shard_map(f: Callable, *, mesh, in_specs, out_specs,
+              check_vma: bool = True) -> Callable:
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check_vma)
 
 
 def axis_size(axis_name) -> int:
-    """Static size of a mapped mesh axis (``jax.lax.axis_size`` is 0.6+).
+    """Static size of a mapped mesh axis."""
+    return jax.lax.axis_size(axis_name)
 
-    On older JAX, ``psum`` of a unit constant is folded eagerly to the
-    static axis size (a Python int), so comprehensions over shards keep
-    working.
-    """
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(axis_name)
-    return jax.lax.psum(1, axis_name)
-
-
-# ---------------------------------------------------------------------------
-# mesh construction
-# ---------------------------------------------------------------------------
 
 def make_mesh(axis_shapes: Sequence[int], axis_names: Sequence[str], *,
               devices: Optional[Sequence[Any]] = None):
-    """``jax.make_mesh`` with Auto axis types where the API supports them."""
+    """``jax.make_mesh`` with Auto axis types."""
     kwargs: Dict[str, Any] = {}
     if devices is not None:
         kwargs["devices"] = devices
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        try:
-            return jax.make_mesh(
-                tuple(axis_shapes), tuple(axis_names),
-                axis_types=(axis_type.Auto,) * len(tuple(axis_names)),
-                **kwargs)
-        except TypeError:  # AxisType exists but make_mesh predates the kwarg
-            pass
-    return jax.make_mesh(tuple(axis_shapes), tuple(axis_names), **kwargs)
+    return jax.make_mesh(
+        tuple(axis_shapes), tuple(axis_names),
+        axis_types=(jax.sharding.AxisType.Auto,) * len(tuple(axis_names)),
+        **kwargs)
 
-
-# ---------------------------------------------------------------------------
-# compiled-executable cost analysis
-# ---------------------------------------------------------------------------
 
 def cost_analysis(compiled) -> Dict[str, float]:
-    """Normalized ``Compiled.cost_analysis()``: always one flat dict.
-
-    New JAX returns a dict; 0.4.x returns a list of per-program dicts (one
-    entry for the single SPMD program); some backends return None.  Missing
-    analysis normalizes to ``{}`` so callers can ``.get(...)`` uniformly.
-    """
-    try:
-        raw = compiled.cost_analysis()
-    except Exception:
-        return {}
-    if raw is None:
-        return {}
-    if isinstance(raw, (list, tuple)):
-        raw = raw[0] if raw else {}
-    return dict(raw)
+    """``Compiled.cost_analysis()`` as a flat dict; a backend that reports
+    no analysis (None) gives ``{}``.  Errors raise."""
+    raw = compiled.cost_analysis()
+    return {} if raw is None else dict(raw)

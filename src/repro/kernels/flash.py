@@ -11,7 +11,11 @@ decode) carry the same numerically delicate recurrence across kv blocks:
 Keeping it in one place pins the rescale ordering and the normalizer
 epsilon once — the conformance harness's permutation-of-arrival property
 test then covers every kernel that calls it.  All helpers operate on the
-kernels' VMEM scratch refs in place.
+kernels' VMEM scratch refs in place.  The per-row statistics ``m`` and
+``l`` are (rows, 1) columns, not 1-d vectors: a column keeps the row axis
+on sublanes, so the TPU compiler tiles the scratch, the broadcasts against
+the (rows, bkv) score block and the kernels' m / l output blocks without a
+lane-to-sublane relayout.
 """
 from __future__ import annotations
 
@@ -32,13 +36,14 @@ def update(m_s, l_s, acc_s, s: jax.Array, valid: jax.Array,
            v_tile: jax.Array) -> None:
     """One kv-block update.  ``s``: (rows, bkv) fp32 scores already set to
     NEG_INF where invalid; ``valid``: bool, same shape (zeroes p exactly so
-    a fully-masked row accumulates nothing); ``v_tile``: (bkv, hd) fp32."""
+    a fully-masked row accumulates nothing); ``v_tile``: (bkv, hd) fp32;
+    ``m_s`` / ``l_s``: (rows, 1)."""
     m_prev = m_s[...]
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=1))
-    p = jnp.where(valid, jnp.exp(s - m_new[:, None]), 0.0)
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+    p = jnp.where(valid, jnp.exp(s - m_new), 0.0)
     corr = jnp.exp(m_prev - m_new)
-    l_s[...] = l_s[...] * corr + jnp.sum(p, axis=1)
-    acc_s[...] = acc_s[...] * corr[:, None] + jax.lax.dot_general(
+    l_s[...] = l_s[...] * corr + jnp.sum(p, axis=1, keepdims=True)
+    acc_s[...] = acc_s[...] * corr + jax.lax.dot_general(
         p, v_tile, (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32)
     m_s[...] = m_new
@@ -46,5 +51,5 @@ def update(m_s, l_s, acc_s, s: jax.Array, valid: jax.Array,
 
 def normalized(acc: jax.Array, l: jax.Array) -> jax.Array:
     """acc / l with the shared epsilon (fully-masked rows emit 0, matching
-    the jnp epilogues)."""
-    return acc / jnp.maximum(l, 1e-30)[:, None]
+    the jnp epilogues); ``l``: (rows, 1)."""
+    return acc / jnp.maximum(l, 1e-30)

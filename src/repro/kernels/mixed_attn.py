@@ -28,6 +28,9 @@ an online-softmax loop over (bq, bkv) tiles.
 Grid: (B, H, Tq/bq, T/bkv) with the kv dim innermost; (m, l, acc) scratch
 carries the flash state across kv blocks.  Scalar operands arrive via
 ``PrefetchScalarGridSpec`` so index_maps and masks can depend on them.
+Codes and codebooks take the per-kv-head layouts of
+``vq_decode_attn.head_codes`` / ``head_codebooks`` (the TPU tiling rule),
+and the m / l partials leave ``chunk_flash_partials`` as (rows, 1) columns.
 """
 from __future__ import annotations
 
@@ -40,12 +43,13 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels import flash
+from repro.kernels.vq_decode_attn import dequant_tile, head_codebooks, head_codes
 
 NEG_INF = flash.NEG_INF
 
 
 def _kernel(offs_ref, q_ref, kl_ref, vl_ref, kc_ref, vc_ref, cbk_ref,
-            cbv_ref, out_ref, m_s, l_s, acc_s, *, bq, bkv, nkb, gph, dg,
+            cbv_ref, out_ref, m_s, l_s, acc_s, *, bq, bkv, nkb, hd,
             causal, softcap, tl):
     ki = pl.program_id(3)
     qi = pl.program_id(2)
@@ -57,26 +61,13 @@ def _kernel(offs_ref, q_ref, kl_ref, vl_ref, kc_ref, vc_ref, cbk_ref,
         flash.init_state(m_s, l_s, acc_s)
 
     # --- assemble the kv tile: dequantized codes or local FP --------------
-    codes_k = kc_ref[0]  # (bkv, gph) int32
-    codes_v = vc_ref[0]
-    hd = gph * dg
-
-    def dequant(cb_ref, codes):
-        parts = [
-            jnp.take(cb_ref[j], codes[:, j], axis=0)  # (bkv, dg)
-            for j in range(gph)
-        ]
-        return jnp.concatenate(parts, axis=-1)  # (bkv, hd)
-
-    k_hat = dequant(cbk_ref, codes_k)
-    v_hat = dequant(cbv_ref, codes_v)
+    k_hat = dequant_tile(kc_ref[0, 0], cbk_ref)  # (bkv, hd) fp32
+    v_hat = dequant_tile(vc_ref[0, 0], cbv_ref)
     k_loc = kl_ref[0, 0]  # (bkv, hd) — local tile (clamped index when remote)
     v_loc = vl_ref[0, 0]
     is_local = jnp.logical_and(ki * bkv >= offset, ki * bkv < offset + tl)
-    k_tile = jnp.where(is_local, k_loc.astype(jnp.float32),
-                       k_hat.astype(jnp.float32))
-    v_tile = jnp.where(is_local, v_loc.astype(jnp.float32),
-                       v_hat.astype(jnp.float32))
+    k_tile = jnp.where(is_local, k_loc.astype(jnp.float32), k_hat)
+    v_tile = jnp.where(is_local, v_loc.astype(jnp.float32), v_hat)
 
     # --- flash update ------------------------------------------------------
     q = q_ref[0, 0].astype(jnp.float32)  # (bq, hd)
@@ -149,21 +140,21 @@ def mixed_flash_attention(
             pl.BlockSpec((1, 1, bq, hd), lambda bi, hi, qi, ki, o: (bi, hi, qi, 0)),
             pl.BlockSpec((1, 1, bkv, hd), li),
             pl.BlockSpec((1, 1, bkv, hd), li),
-            pl.BlockSpec((1, bkv, gph), lambda bi, hi, qi, ki, o: (bi, ki, hi // rep)),
-            pl.BlockSpec((1, bkv, gph), lambda bi, hi, qi, ki, o: (bi, ki, hi // rep)),
-            pl.BlockSpec((gph, k, dg), lambda bi, hi, qi, ki, o: (hi // rep, 0, 0)),
-            pl.BlockSpec((gph, k, dg), lambda bi, hi, qi, ki, o: (hi // rep, 0, 0)),
+            pl.BlockSpec((1, 1, bkv, gph), lambda bi, hi, qi, ki, o: (bi, hi // rep, ki, 0)),
+            pl.BlockSpec((1, 1, bkv, gph), lambda bi, hi, qi, ki, o: (bi, hi // rep, ki, 0)),
+            pl.BlockSpec((gph, k, hd), lambda bi, hi, qi, ki, o: (hi // rep, 0, 0)),
+            pl.BlockSpec((gph, k, hd), lambda bi, hi, qi, ki, o: (hi // rep, 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, 1, bq, hd),
                                lambda bi, hi, qi, ki, o: (bi, hi, qi, 0)),
         scratch_shapes=[
-            pltpu.VMEM((bq,), jnp.float32),
-            pltpu.VMEM((bq,), jnp.float32),
+            pltpu.VMEM((bq, 1), jnp.float32),
+            pltpu.VMEM((bq, 1), jnp.float32),
             pltpu.VMEM((bq, hd), jnp.float32),
         ],
     )
     kern = functools.partial(
-        _kernel, bq=bq, bkv=bkv, nkb=nkb, gph=gph, dg=dg, causal=causal,
+        _kernel, bq=bq, bkv=bkv, nkb=nkb, hd=hd, causal=causal,
         softcap=softcap, tl=tl)
     offset = jnp.asarray(offset, jnp.int32)
     qs = offset if q_start is None else jnp.asarray(q_start, jnp.int32)
@@ -173,7 +164,9 @@ def mixed_flash_attention(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         interpret=resolve_interpret(interpret),
-    )(offs, q, k_local, v_local, k_codes, v_codes, cb_k, cb_v)
+    )(offs, q, k_local, v_local, head_codes(k_codes, hkv),
+      head_codes(v_codes, hkv), head_codebooks(cb_k, hkv),
+      head_codebooks(cb_v, hkv))
 
 
 # ---------------------------------------------------------------------------
@@ -282,8 +275,8 @@ def chunk_flash_attention(
         out_specs=pl.BlockSpec((1, 1, bq, hd),
                                lambda bi, hi, qi, ki, cs: (bi, hi, qi, 0)),
         scratch_shapes=[
-            pltpu.VMEM((bq,), jnp.float32),
-            pltpu.VMEM((bq,), jnp.float32),
+            pltpu.VMEM((bq, 1), jnp.float32),
+            pltpu.VMEM((bq, 1), jnp.float32),
             pltpu.VMEM((bq, hd), jnp.float32),
         ],
     )
@@ -396,14 +389,14 @@ def chunk_flash_partials(
             pl.BlockSpec((1, bkv), lambda bi, hi, qi, ki, cs: (0, ki)),
         ],
         out_specs=[
-            pl.BlockSpec((1, 1, bq), lambda bi, hi, qi, ki, cs: (bi, hi, qi)),
-            pl.BlockSpec((1, 1, bq), lambda bi, hi, qi, ki, cs: (bi, hi, qi)),
+            pl.BlockSpec((1, 1, bq, 1), lambda bi, hi, qi, ki, cs: (bi, hi, qi, 0)),
+            pl.BlockSpec((1, 1, bq, 1), lambda bi, hi, qi, ki, cs: (bi, hi, qi, 0)),
             pl.BlockSpec((1, 1, bq, hd),
                          lambda bi, hi, qi, ki, cs: (bi, hi, qi, 0)),
         ],
         scratch_shapes=[
-            pltpu.VMEM((bq,), jnp.float32),
-            pltpu.VMEM((bq,), jnp.float32),
+            pltpu.VMEM((bq, 1), jnp.float32),
+            pltpu.VMEM((bq, 1), jnp.float32),
             pltpu.VMEM((bq, hd), jnp.float32),
         ],
     )
@@ -414,11 +407,11 @@ def chunk_flash_partials(
         kern,
         grid_spec=grid_spec,
         out_shape=[
-            jax.ShapeDtypeStruct((b, h, wq), jnp.float32),
-            jax.ShapeDtypeStruct((b, h, wq), jnp.float32),
+            jax.ShapeDtypeStruct((b, h, wq, 1), jnp.float32),
+            jax.ShapeDtypeStruct((b, h, wq, 1), jnp.float32),
             jax.ShapeDtypeStruct((b, h, wq, hd), jnp.float32),
         ],
         interpret=resolve_interpret(interpret),
     )(jnp.reshape(jnp.asarray(chunk_start, jnp.int32), (1,)), qt, kt, vt,
       k_pos.astype(jnp.int32).reshape(1, sk))
-    return m[:, :, :w], l[:, :, :w], jnp.moveaxis(acc, 1, 2)[:, :w]
+    return m[:, :, :w, 0], l[:, :, :w, 0], jnp.moveaxis(acc, 1, 2)[:, :w]

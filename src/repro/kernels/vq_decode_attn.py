@@ -24,6 +24,13 @@ collective), exactly mirroring ``attention._decode_sharded``.
 Grid: (B, Hkv, S/bkv), kv innermost; scratch carries the flash state.
 Key spans that don't divide ``block_kv`` are zero-padded and the padded
 slots masked out via the static real length.
+
+TPU tiling: every block's last two dims are (8, 128)-aligned or the whole
+array's, so the coded kernels read each kv head's codes from a
+(B, Hkv, S, gph) copy (``head_codes``) and dequantize with one-hot x
+codebook matmuls (``dequant_tile``): the TPU compiler lowers neither a
+(bkv, gph) window onto the G axis nor a gather inside a kernel.  The m / l
+partials leave the kernels as (rows, 1) columns (``flash.update``).
 """
 from __future__ import annotations
 
@@ -40,9 +47,51 @@ from repro.kernels import flash
 NEG_INF = flash.NEG_INF
 
 
+def head_codes(codes: jax.Array, hkv: int) -> jax.Array:
+    """(B, S, G) codes of any integer dtype -> (B, Hkv, S, gph) int32: each
+    kv head's ``gph = G / Hkv`` groups as one array axis, so a (bkv, gph)
+    block spans that axis whole."""
+    b, s, g = codes.shape
+    return jnp.moveaxis(
+        codes.astype(jnp.int32).reshape(b, s, hkv, g // hkv), 2, 1)
+
+
+def head_codebooks(cb: jax.Array, hkv: int) -> jax.Array:
+    """(G, K, dg) codebooks -> (G, K, hd): group ``g`` zero-padded into its
+    own ``dg`` columns of its kv head's dim, so the sum of a head's ``gph``
+    one-hot products is its (bkv, hd) tile with no lane concatenation.
+    Padding, not a placement matmul, keeps the values exact.  Kv head ``i``
+    reads the (gph, K, hd) block ``i``."""
+    g, k, dg = cb.shape
+    gph = g // hkv
+    hd = gph * dg
+    cbh = cb.reshape(hkv, gph, k, dg)
+    return jnp.stack(
+        [jnp.pad(cbh[:, j], ((0, 0), (0, 0), (j * dg, hd - (j + 1) * dg)))
+         for j in range(gph)], axis=1).reshape(g, k, hd)
+
+
+def dequant_tile(codes: jax.Array, cb_ref) -> jax.Array:
+    """codes (bkv, gph) int32 x ``cb_ref`` (gph, K, hd) -> the (bkv, hd)
+    fp32 tile, as one one-hot x codebook matmul per group.  HIGHEST
+    precision keeps the selected fp32 codebook rows exact on the MXU, so
+    this equals the ``jnp.take`` dequantize of ``ref.dequant_head``."""
+    gph, k, _ = cb_ref.shape
+    ids = jax.lax.broadcasted_iota(jnp.int32, (codes.shape[0], k), 1)
+    tile = None
+    for j in range(gph):
+        onehot = (codes[:, j:j + 1] == ids).astype(jnp.float32)
+        part = jax.lax.dot_general(
+            onehot, cb_ref[j].astype(jnp.float32), (((1,), (0,)), ((), ())),
+            precision=jax.lax.Precision.HIGHEST,
+            preferred_element_type=jnp.float32)
+        tile = part if tile is None else tile + part
+    return tile
+
+
 def _kernel(lengths_ref, q_ref, kc_ref, vc_ref, cbk_ref, cbv_ref,
             m_ref, l_ref, acc_ref, m_s, l_s, acc_s, *,
-            bkv, nkb, s_real, gph, dg, rep, softcap):
+            bkv, nkb, s_real, hd, rep, softcap):
     ki = pl.program_id(2)
     bi = pl.program_id(0)
     length = lengths_ref[bi]
@@ -51,17 +100,8 @@ def _kernel(lengths_ref, q_ref, kc_ref, vc_ref, cbk_ref, cbv_ref,
     def _init():
         flash.init_state(m_s, l_s, acc_s)
 
-    hd = gph * dg
-    codes_k = kc_ref[0]  # (bkv, gph)
-    codes_v = vc_ref[0]
-
-    def dequant(cb_ref, codes):
-        parts = [jnp.take(cb_ref[j], codes[:, j], axis=0)
-                 for j in range(gph)]
-        return jnp.concatenate(parts, axis=-1)  # (bkv, hd)
-
-    k_tile = dequant(cbk_ref, codes_k).astype(jnp.float32)
-    v_tile = dequant(cbv_ref, codes_v).astype(jnp.float32)
+    k_tile = dequant_tile(kc_ref[0, 0], cbk_ref)  # (bkv, hd)
+    v_tile = dequant_tile(vc_ref[0, 0], cbv_ref)
 
     q = q_ref[0, 0].astype(jnp.float32)  # (rep, hd) — queries of this kv head
     s = jax.lax.dot_general(q, k_tile, (((1,), (1,)), ((), ())),
@@ -109,13 +149,13 @@ def vq_decode_attention(
     rep = h // hkv
     gph = g // hkv
     assert gph * dg == hd, (gph, dg, hd)
-    k_codes = k_codes.astype(jnp.int32)  # uint8/16 code slabs index as int32
-    v_codes = v_codes.astype(jnp.int32)
     bkv = min(block_kv, s)
     pad = (-s) % bkv
+    kc = head_codes(k_codes, hkv)  # (B, Hkv, S, gph) int32
+    vc = head_codes(v_codes, hkv)
     if pad:  # zero-pad to a block multiple; code 0 is valid, mask rejects
-        k_codes = jnp.pad(k_codes, ((0, 0), (0, pad), (0, 0)))
-        v_codes = jnp.pad(v_codes, ((0, 0), (0, pad), (0, 0)))
+        kc = jnp.pad(kc, ((0, 0), (0, 0), (0, pad), (0, 0)))
+        vc = jnp.pad(vc, ((0, 0), (0, 0), (0, pad), (0, 0)))
     nkb = (s + pad) // bkv
 
     qg = q.reshape(b, hkv, rep, hd)
@@ -125,34 +165,35 @@ def vq_decode_attention(
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, 1, rep, hd), lambda bi, gi, ki, L: (bi, gi, 0, 0)),
-            pl.BlockSpec((1, bkv, gph), lambda bi, gi, ki, L: (bi, ki, gi)),
-            pl.BlockSpec((1, bkv, gph), lambda bi, gi, ki, L: (bi, ki, gi)),
-            pl.BlockSpec((gph, k, dg), lambda bi, gi, ki, L: (gi, 0, 0)),
-            pl.BlockSpec((gph, k, dg), lambda bi, gi, ki, L: (gi, 0, 0)),
+            pl.BlockSpec((1, 1, bkv, gph), lambda bi, gi, ki, L: (bi, gi, ki, 0)),
+            pl.BlockSpec((1, 1, bkv, gph), lambda bi, gi, ki, L: (bi, gi, ki, 0)),
+            pl.BlockSpec((gph, k, hd), lambda bi, gi, ki, L: (gi, 0, 0)),
+            pl.BlockSpec((gph, k, hd), lambda bi, gi, ki, L: (gi, 0, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, 1, rep), lambda bi, gi, ki, L: (bi, gi, 0)),
-            pl.BlockSpec((1, 1, rep), lambda bi, gi, ki, L: (bi, gi, 0)),
+            pl.BlockSpec((1, 1, rep, 1), lambda bi, gi, ki, L: (bi, gi, 0, 0)),
+            pl.BlockSpec((1, 1, rep, 1), lambda bi, gi, ki, L: (bi, gi, 0, 0)),
             pl.BlockSpec((1, 1, rep, hd), lambda bi, gi, ki, L: (bi, gi, 0, 0)),
         ],
         scratch_shapes=[
-            pltpu.VMEM((rep,), jnp.float32),
-            pltpu.VMEM((rep,), jnp.float32),
+            pltpu.VMEM((rep, 1), jnp.float32),
+            pltpu.VMEM((rep, 1), jnp.float32),
             pltpu.VMEM((rep, hd), jnp.float32),
         ],
     )
-    kern = functools.partial(_kernel, bkv=bkv, nkb=nkb, s_real=s, gph=gph,
-                             dg=dg, rep=rep, softcap=softcap)
+    kern = functools.partial(_kernel, bkv=bkv, nkb=nkb, s_real=s, hd=hd,
+                             rep=rep, softcap=softcap)
     m, l, acc = pl.pallas_call(
         kern,
         grid_spec=grid_spec,
         out_shape=[
-            jax.ShapeDtypeStruct((b, hkv, rep), jnp.float32),
-            jax.ShapeDtypeStruct((b, hkv, rep), jnp.float32),
+            jax.ShapeDtypeStruct((b, hkv, rep, 1), jnp.float32),
+            jax.ShapeDtypeStruct((b, hkv, rep, 1), jnp.float32),
             jax.ShapeDtypeStruct((b, hkv, rep, hd), jnp.float32),
         ],
         interpret=resolve_interpret(interpret),
-    )(lengths.astype(jnp.int32), qg, k_codes, v_codes, cb_k, cb_v)
+    )(lengths.astype(jnp.int32), qg, kc, vc, head_codebooks(cb_k, hkv),
+      head_codebooks(cb_v, hkv))
     return (m.reshape(b, h), l.reshape(b, h), acc.reshape(b, h, hd))
 
 
@@ -241,13 +282,13 @@ def fp_decode_attention(
             pl.BlockSpec((1, 1, bkv, hd), lambda bi, gi, ki, L: (bi, gi, ki, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, 1, rep), lambda bi, gi, ki, L: (bi, gi, 0)),
-            pl.BlockSpec((1, 1, rep), lambda bi, gi, ki, L: (bi, gi, 0)),
+            pl.BlockSpec((1, 1, rep, 1), lambda bi, gi, ki, L: (bi, gi, 0, 0)),
+            pl.BlockSpec((1, 1, rep, 1), lambda bi, gi, ki, L: (bi, gi, 0, 0)),
             pl.BlockSpec((1, 1, rep, hd), lambda bi, gi, ki, L: (bi, gi, 0, 0)),
         ],
         scratch_shapes=[
-            pltpu.VMEM((rep,), jnp.float32),
-            pltpu.VMEM((rep,), jnp.float32),
+            pltpu.VMEM((rep, 1), jnp.float32),
+            pltpu.VMEM((rep, 1), jnp.float32),
             pltpu.VMEM((rep, hd), jnp.float32),
         ],
     )
@@ -257,8 +298,8 @@ def fp_decode_attention(
         kern,
         grid_spec=grid_spec,
         out_shape=[
-            jax.ShapeDtypeStruct((b, hkv, rep), jnp.float32),
-            jax.ShapeDtypeStruct((b, hkv, rep), jnp.float32),
+            jax.ShapeDtypeStruct((b, hkv, rep, 1), jnp.float32),
+            jax.ShapeDtypeStruct((b, hkv, rep, 1), jnp.float32),
             jax.ShapeDtypeStruct((b, hkv, rep, hd), jnp.float32),
         ],
         interpret=resolve_interpret(interpret),

@@ -17,6 +17,7 @@ import jax
 import numpy as np
 
 from repro.configs import get_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import model_factory as mf
 from repro.serving.cache_backend import CACHE_MODES
 from repro.serving.engine import ServingEngine
@@ -89,6 +90,7 @@ def main() -> None:
     ap.add_argument("--checkpoint", default="")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    enable_compile_cache()
 
     if (args.priority or args.deadline) and not args.continuous:
         raise SystemExit("--priority/--deadline need --continuous (the "

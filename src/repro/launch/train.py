@@ -27,6 +27,7 @@ from repro.configs import SHAPE_BY_NAME, get_config
 from repro.configs.base import ShapeSpec
 from repro.data import pipeline
 from repro.launch import steps as steps_mod
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_host_mesh, make_production_mesh
 from repro.training import checkpoint, optimizer as opt_mod
 from repro.training.trainer import Trainer
@@ -123,6 +124,7 @@ def main() -> None:
     ap.add_argument("--metrics-jsonl", default="",
                     help="append step metrics to this JSONL file")
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:

@@ -276,10 +276,7 @@ def analyze_compiled(compiled) -> Dict[str, float]:
     """Trip-weighted totals for a jit-compiled executable, plus the raw
     (unweighted) XLA numbers under ``raw_flops`` / ``raw_bytes_accessed``.
 
-    The raw numbers come through the version-normalizing compat accessor —
-    on jax 0.4.x the executable reports a *list* of per-program cost dicts,
-    which is what used to crash the roofline path with
-    ``TypeError: list indices must be integers``.
+    The raw numbers come through ``compat.cost_analysis`` (one flat dict).
     """
     out = analyze(compiled.as_text())
     raw = normalized_cost_analysis(compiled)

@@ -48,6 +48,7 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
 from repro.compat import shard_map
@@ -462,6 +463,17 @@ class CacheBackend:
         return True
 
     # -- engine level (host) ------------------------------------------------
+    def commit_caches(self, caches, ctx):
+        """Place a host-built cache tree where the compiled serving steps
+        return it (identity off the mesh; see ``ShardedBackend``)."""
+        return caches
+
+    def commit_rows(self, x, ctx):
+        """Place host-built per-row step state (lengths, budgets, running
+        logits) where the compiled steps return it (identity off the
+        mesh)."""
+        return x
+
     def make_state(self, cfg, *, slots: int, max_len: int, ctx, dtype=None,
                    page_size: int = 16, num_pages: Optional[int] = None):
         return kvc.SlabCache(cfg, slots=slots, max_len=max_len, ctx=ctx,
@@ -1106,6 +1118,38 @@ class ShardedBackend(CacheBackend):
                                      ctx=ctx, dtype=dtype,
                                      page_size=page_size,
                                      num_pages=num_pages)
+
+    # jit keys each compiled program on its arguments' shardings: a cache or
+    # row vector built on the host enters the first step call uncommitted,
+    # while every later call gets the step's own mesh-placed outputs — two
+    # traces and two compiles of the same step.  Committing host-built state
+    # to the shardings the steps return makes the first call the last
+    # compile.
+    def commit_caches(self, caches, ctx):
+        """Global attention layers: slabs and the fp prefill-view scratch
+        (reps, B, S, ...) split over the sequence axis, page pools
+        (reps, N, ...) over their page-id axis — the shard_map out_specs of
+        the sharded steps.  Everything else (rings, recurrent state) is
+        replicated."""
+        from repro.models import transformer as tlm
+
+        mesh, axis = ctx.mesh.mesh, ctx.mesh.seq_axis
+        bspec = ctx.mesh.batch_axes if ctx.mesh.batch_axes else None
+
+        def place(kind, leaves):
+            if kind not in tlm.ATTN_KINDS or attn.kind_window(kind, ctx.cfg):
+                return jax.device_put(leaves, NamedSharding(mesh, P()))
+            return {name: jax.device_put(x, NamedSharding(
+                        mesh, P(None, axis) if name.endswith("_pages")
+                        else P(None, bspec, axis)))
+                    for name, x in leaves.items()}
+
+        return [{sub: place(kinds[int(sub[len("sub"):])], leaves)
+                 for sub, leaves in stage.items()}
+                for (kinds, _), stage in zip(tlm.stages(ctx.cfg), caches)]
+
+    def commit_rows(self, x, ctx):
+        return jax.device_put(x, NamedSharding(ctx.mesh.mesh, P()))
 
     @property
     def preemptible(self) -> bool:

@@ -24,6 +24,7 @@ the contiguous-layout feature.
 """
 from __future__ import annotations
 
+import functools
 from typing import Optional, Sequence, Tuple
 
 import jax
@@ -110,17 +111,13 @@ class DisaggregatedEngine:
                     f"{group}-group devices (the shard cache splits the "
                     f"sequence dimension evenly)")
         devices = jax.devices()
-        if self.num_prefill + self.num_decode <= len(devices):
-            pre = devices[:self.num_prefill]
-            dec = devices[self.num_prefill:self.num_prefill + self.num_decode]
-        else:  # small hosts: groups overlap, accounting still holds
-            if max(self.num_prefill, self.num_decode) > len(devices):
-                raise ValueError(
-                    f"split {split!r} needs "
-                    f"{max(self.num_prefill, self.num_decode)} devices, "
-                    f"host has {len(devices)}")
-            pre = devices[:self.num_prefill]
-            dec = devices[-self.num_decode:]
+        if self.num_prefill + self.num_decode > len(devices):
+            raise ValueError(
+                f"split {split!r} needs "
+                f"{self.num_prefill + self.num_decode} devices (disjoint "
+                f"prefill and decode groups), host has {len(devices)}")
+        pre = devices[:self.num_prefill]
+        dec = devices[self.num_prefill:self.num_prefill + self.num_decode]
         self.prefill_engine = ServingEngine(
             cfg, params, max_len=max_len, astra_mode=astra_mode,
             cache_mode=cache_mode, decode_chunk=decode_chunk,
@@ -131,7 +128,7 @@ class DisaggregatedEngine:
             cache_mode=cache_mode, decode_chunk=decode_chunk,
             use_pallas=use_pallas,
             mesh_ctx=_mesh_for(dec, self.num_decode))
-        self.decode_device = dec[0] if self.num_decode == 1 else None
+        self.decode_device = dec[0]
         self.max_len = max_len
         self.cache_mode = cache_mode
         self.bandwidths_mbps = tuple(bandwidths_mbps)
@@ -147,11 +144,15 @@ class DisaggregatedEngine:
         self.migration_bytes += coded
         self.migration_fp_bytes += fp_equiv
         self.migrations += 1
-        host = jax.device_get((last_logits, caches))
-        if self.decode_device is not None:
-            return jax.device_put(host, self.decode_device)
-        # D > 1: the decode mesh's shard_map re-shards on first use
-        return jax.device_put(host[0]), jax.device_put(host[1])
+        host_logits, host_caches = jax.device_get((last_logits, caches))
+        de = self.decode_engine
+        if self.num_decode == 1:
+            return jax.device_put((host_logits, host_caches),
+                                  self.decode_device)
+        # D > 1: straight onto the decode mesh, in the shardings its
+        # decode step returns (the first step then compiles the last time)
+        return (de.backend.commit_rows(host_logits, de.decode_ctx),
+                de.backend.commit_caches(host_caches, de.decode_ctx))
 
     def migration_report(self) -> dict:
         """fp-vs-coded hand-off bytes and transfer times at the bandwidth
@@ -198,10 +199,11 @@ class DisaggregatedEngine:
         first, done_h, prefill_logits = jax.device_get(
             (cur, done, last_logits))
         out = [[int(first[i])] for i in range(b)]
-        lengths = jnp.asarray(lens)
+        rows = functools.partial(de.backend.commit_rows, ctx=de.decode_ctx)
+        lengths = rows(jnp.asarray(lens))
         budget = max_new_tokens - 1
         chunk = de.decode_chunk
-        remaining = jnp.full((b,), budget, jnp.int32)
+        remaining = rows(jnp.full((b,), budget, jnp.int32))
         emitted = 0
         while emitted < budget and not done_h.all():
             rng, sub = jax.random.split(rng)

@@ -25,6 +25,7 @@ CPU).
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import List, Optional, Sequence
 
 import jax
@@ -233,11 +234,14 @@ class ServingEngine:
         if kv is not None:
             caches = kv.init_cache(b, prefill_scratch=True)
         else:
-            caches = tlm.init_lm_cache(self.cfg, b, self.max_len,
-                                       self.prefill_ctx, self.cache_dtype,
-                                       prefill_scratch=True)
-        lengths = jnp.asarray(lens)
-        last_logits = jnp.zeros((b, self.cfg.vocab_size), jnp.float32)
+            caches = self.backend.commit_caches(
+                tlm.init_lm_cache(self.cfg, b, self.max_len,
+                                  self.prefill_ctx, self.cache_dtype,
+                                  prefill_scratch=True), self.prefill_ctx)
+        rows = functools.partial(self.backend.commit_rows,
+                                 ctx=self.prefill_ctx)
+        lengths = rows(jnp.asarray(lens))
+        last_logits = rows(jnp.zeros((b, self.cfg.vocab_size), jnp.float32))
         for s0, w in serving_steps.plan_chunks(int(lens.max()),
                                                self.prefill_buckets):
             chunk = np.zeros((b, w), np.int32)
@@ -295,13 +299,15 @@ class ServingEngine:
         self.host_syncs += 1
         out = [[int(first[i])] for i in range(b)]
 
-        lengths = jnp.asarray(lens)
+        rows = functools.partial(self.backend.commit_rows,
+                                 ctx=self.decode_ctx)
+        lengths = rows(jnp.asarray(lens))
         budget = max_new_tokens - 1
         # num_steps stays pinned to decode_chunk (ONE compiled scan) even for
         # short budgets — the per-row `remaining` mask truncates the tail, so
         # varying max_new_tokens never re-specializes the decode graph.
         chunk = self.decode_chunk
-        remaining = jnp.full((b,), budget, jnp.int32)
+        remaining = rows(jnp.full((b,), budget, jnp.int32))
         emitted = 0
         if self.spec_k:
             k = self.spec_k

@@ -1292,11 +1292,12 @@ class PagedKVCache:
         the fp prefill-view slabs chunked vq prefill carries)."""
         from repro.models import transformer as tlm
 
-        return tlm.init_lm_cache(self.cfg, batch or self.slots, self.max_len,
-                                 self.ctx, self.dtype,
-                                 page_size=self.page_size,
-                                 num_pages=self.num_pages_by_group,
-                                 prefill_scratch=prefill_scratch)
+        return self.ctx.backend.commit_caches(
+            tlm.init_lm_cache(self.cfg, batch or self.slots, self.max_len,
+                              self.ctx, self.dtype,
+                              page_size=self.page_size,
+                              num_pages=self.num_pages_by_group,
+                              prefill_scratch=prefill_scratch), self.ctx)
 
     def pool_bytes(self, caches=None) -> int:
         """Measured page-pool bytes (materialized if ``caches`` given, else
@@ -1370,9 +1371,10 @@ class SlabCache:
                    prefill_scratch: bool = False):
         from repro.models import transformer as tlm
 
-        return tlm.init_lm_cache(self.cfg, batch or self.slots, self.max_len,
-                                 self.ctx, self.dtype,
-                                 prefill_scratch=prefill_scratch)
+        return self.ctx.backend.commit_caches(
+            tlm.init_lm_cache(self.cfg, batch or self.slots, self.max_len,
+                              self.ctx, self.dtype,
+                              prefill_scratch=prefill_scratch), self.ctx)
 
     def pool_bytes(self, caches=None) -> int:
         return 0

@@ -229,8 +229,10 @@ class ContinuousBatchingEngine:
         self.preempt_mode = preempt_mode
         self.preemptions = 0  # preemption events (a request may repeat)
         self.preempt_log: List = []  # (step, uid) per event
-        self.lengths = jnp.zeros((slots,), jnp.int32)
-        self.cur_token = jnp.zeros((slots,), jnp.int32)
+        self.lengths = self.backend.commit_rows(
+            jnp.zeros((slots,), jnp.int32), self.decode_ctx)
+        self.cur_token = self.backend.commit_rows(
+            jnp.zeros((slots,), jnp.int32), self.decode_ctx)
         self.active: List[Optional[Request]] = [None] * slots
         self.queue: List[Request] = []
         self.finished: List[Request] = []
@@ -682,7 +684,9 @@ class ContinuousBatchingEngine:
             plan=serving_steps.plan_chunks(n, self.prefill_buckets,
                                            start=reuse),
             next_chunk=0, caches=caches,
-            last_logits=jnp.zeros((1, self.cfg.vocab_size), jnp.float32))
+            last_logits=self.backend.commit_rows(
+                jnp.zeros((1, self.cfg.vocab_size), jnp.float32),
+                self.prefill_ctx))
 
     def _advance_pending(self) -> None:
         """Run at most ONE prefill chunk — the scheduler's

@@ -1,4 +1,4 @@
-"""repro.compat: version-adaptive JAX seams + the no-direct-use invariant."""
+"""repro.compat: the JAX API seams + the no-direct-use invariant."""
 import jax
 import jax.numpy as jnp
 import pytest
@@ -41,3 +41,19 @@ def test_cost_analysis_normalized_to_flat_dict():
     cost = compat.cost_analysis(compiled)
     assert isinstance(cost, dict)
     assert cost.get("flops", 0.0) > 0.0
+
+
+def test_cost_analysis_lets_errors_raise():
+    """A failing analysis is an error, not an empty report; a backend with
+    no analysis at all (None) gives {}."""
+    class Broken:
+        def cost_analysis(self):
+            raise RuntimeError("analysis failed")
+
+    class Absent:
+        def cost_analysis(self):
+            return None
+
+    with pytest.raises(RuntimeError, match="analysis failed"):
+        compat.cost_analysis(Broken())
+    assert compat.cost_analysis(Absent()) == {}

@@ -1,6 +1,8 @@
 """Launcher substrate: step bundles build, lower AND compile on a tiny mesh
 with reduced configs — integration coverage for steps.py/sharding.py without
 the 512-device dry-run environment."""
+import os
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -104,3 +106,19 @@ def test_expert_parallel_override_targets_expert_dim():
 def test_host_mesh_shapes():
     m = make_host_mesh(1, 1)
     assert dict(m.shape) == {"data": 1, "model": 1}
+
+
+def test_compile_cache_dir_honours_env_and_is_fixed(monkeypatch, tmp_path):
+    """The entry points' compile cache: ``JAX_COMPILATION_CACHE_DIR`` wins
+    when set; otherwise one fixed directory inside the checkout, the same
+    on every call (a moving directory would never hit).  Only the path is
+    resolved here: the tests never turn the cache on."""
+    from repro.launch import compile_cache
+
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    assert compile_cache.compile_cache_dir() == str(tmp_path)
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    first = compile_cache.compile_cache_dir()
+    assert first == compile_cache.compile_cache_dir()
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    assert first == os.path.join(root, ".jax_cache")

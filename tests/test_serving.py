@@ -23,6 +23,17 @@ def small_lm(arch="gpt2-small", astra=False):
     return cfg, params
 
 
+def test_disagg_needs_disjoint_device_groups():
+    """A P:D split the host cannot give disjoint devices is refused, not
+    run with the prefill and decode groups overlapping."""
+    from repro.serving.disagg import DisaggregatedEngine
+
+    cfg, params = small_lm()
+    n = jax.device_count()
+    with pytest.raises(ValueError, match=f"needs {n + 1} devices"):
+        DisaggregatedEngine(cfg, params, max_len=64 * n, split=f"{n}:1")
+
+
 def test_greedy_decode_matches_teacher_forcing():
     """Greedy generation through the KV-cache path must match argmax of the
     cache-free full forward at every step (astra off => exact)."""
