@@ -161,6 +161,7 @@ def mixed_flash_attention(
     offs = jnp.stack([offset, qs]).reshape(2)
     return pl.pallas_call(
         kern,
+        name="mixed_flash_attention",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         interpret=resolve_interpret(interpret),
@@ -284,6 +285,7 @@ def chunk_flash_attention(
                              causal=causal, window=window, softcap=softcap)
     out = pl.pallas_call(
         kern,
+        name="chunk_flash_attention",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, h, wq, hd), jnp.float32),
         interpret=resolve_interpret(interpret),
@@ -405,6 +407,7 @@ def chunk_flash_partials(
                              softcap=softcap)
     m, l, acc = pl.pallas_call(
         kern,
+        name="chunk_flash_partials",
         grid_spec=grid_spec,
         out_shape=[
             jax.ShapeDtypeStruct((b, h, wq, 1), jnp.float32),

@@ -72,6 +72,7 @@ def vq_assign(
     grid = (g, t // bt, nk)
     codes = pl.pallas_call(
         functools.partial(_kernel, bk=bk, nk=nk),
+        name="vq_assign",
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, bt, dg), lambda gi, ti, ki: (gi, ti, 0)),

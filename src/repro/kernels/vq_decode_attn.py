@@ -185,6 +185,7 @@ def vq_decode_attention(
                              rep=rep, softcap=softcap)
     m, l, acc = pl.pallas_call(
         kern,
+        name="vq_decode_attention",
         grid_spec=grid_spec,
         out_shape=[
             jax.ShapeDtypeStruct((b, hkv, rep, 1), jnp.float32),
@@ -296,6 +297,7 @@ def fp_decode_attention(
                              rep=rep, window=window, softcap=softcap)
     m, l, acc = pl.pallas_call(
         kern,
+        name="fp_decode_attention",
         grid_spec=grid_spec,
         out_shape=[
             jax.ShapeDtypeStruct((b, hkv, rep, 1), jnp.float32),

@@ -153,6 +153,10 @@ def main() -> None:
         print(f"  TTFT steps: mean {stats['mean_ttft_steps']:.1f} "
               f"p50 {stats['p50_ttft_steps']:.0f} "
               f"p99 {stats['p99_ttft_steps']:.0f} | "
+              f"TTFT ms p50 {stats['ttft_ms_p50']:.1f} "
+              f"p90 {stats['ttft_ms_p90']:.1f} | "
+              f"end-to-end ms p50 {stats['e2e_ms_p50']:.1f} "
+              f"p90 {stats['e2e_ms_p90']:.1f} | "
               f"stall episodes {stats['admission_stalls']} | "
               f"preemptions {stats['preemptions']}")
         print(f"  SLO: {slo['met']}/{slo['requests']} met "

@@ -73,6 +73,7 @@ def _rms(x, scale, eps=1e-6):
     return (x.astype(jnp.float32) * jax.lax.rsqrt(var + eps) * scale).astype(x.dtype)
 
 
+@jax.named_scope("qkv")
 def qkv(params, x: jax.Array, cfg, positions, theta: float):
     b, t, _ = x.shape
     h, hkv, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
@@ -85,6 +86,13 @@ def qkv(params, x: jax.Array, cfg, positions, theta: float):
     q = apply_rope(q, positions, theta)
     k = apply_rope(k, positions, theta)
     return q, k, v
+
+
+@jax.named_scope("attn_out")
+def out_proj(params, out: jax.Array) -> jax.Array:
+    """(B, T, ...) attention output -> (B, T, D) through ``wo``."""
+    b, t = out.shape[:2]
+    return out.reshape(b, t, -1) @ params["wo"]
 
 
 # ---------------------------------------------------------------------------
@@ -109,7 +117,7 @@ def attention_forward(
     """Returns (y, aux, new_cache).  aux = dict(commit=.., navq=(per-dim
     residual mean/var for K and V) or zeros)."""
     cfg = ctx.cfg
-    b, t, _ = x.shape
+    t = x.shape[1]
     window = kind_window(kind, cfg)
     theta = kind_theta(kind, cfg)
     positions = jnp.arange(t)[None, :]
@@ -117,38 +125,40 @@ def attention_forward(
     cap = cfg.attn_logit_softcap
 
     aux = _zero_aux(cfg)
-    if ctx.astra_on and kind != "local" and ctx.astra_mode == "sim":
-        out, a = astra_kv_attention_sim(
-            q, k, v, vq_params["k"], vq_params["v"], cfg.astra,
-            num_shards=ctx.num_sim_shards, causal=causal, window=window,
-            softcap=cap, train=ctx.train, rng=rng,
-            navq_stats_k=navq_stats["k"] if navq_stats else None,
-            navq_stats_v=navq_stats["v"] if navq_stats else None)
-        aux = _aux_from_sim(a, cfg)
-    elif ctx.astra_on and kind != "local" and ctx.astra_mode == "spmd":
-        out = astra_kv_attention_spmd(
-            ctx.mesh, q, k, v,
-            vq_params["k"]["codebook"], vq_params["v"]["codebook"],
-            cfg.astra, causal=causal, window=window, softcap=cap,
-            chunk=ctx.attn_chunk)
-    elif ctx.seq_sharded:
-        # SP baseline (Voltage): full-precision K/V all-gather.  Local (SWA)
-        # layers take the same path; the window mask bounds useful work.
-        out = sp_full_attention_spmd(
-            ctx.mesh, q, k, v, causal=causal, window=window, softcap=cap,
-            chunk=ctx.attn_chunk)
-    else:
-        pos = jnp.arange(t)
-        out = full_attention(q, k, v, q_pos=pos, k_pos=pos, causal=causal,
-                             window=window, softcap=cap)
+    with jax.named_scope("attn_kernel"):
+        if ctx.astra_on and kind != "local" and ctx.astra_mode == "sim":
+            out, a = astra_kv_attention_sim(
+                q, k, v, vq_params["k"], vq_params["v"], cfg.astra,
+                num_shards=ctx.num_sim_shards, causal=causal, window=window,
+                softcap=cap, train=ctx.train, rng=rng,
+                navq_stats_k=navq_stats["k"] if navq_stats else None,
+                navq_stats_v=navq_stats["v"] if navq_stats else None)
+            aux = _aux_from_sim(a, cfg)
+        elif ctx.astra_on and kind != "local" and ctx.astra_mode == "spmd":
+            out = astra_kv_attention_spmd(
+                ctx.mesh, q, k, v,
+                vq_params["k"]["codebook"], vq_params["v"]["codebook"],
+                cfg.astra, causal=causal, window=window, softcap=cap,
+                chunk=ctx.attn_chunk)
+        elif ctx.seq_sharded:
+            # SP baseline (Voltage): full-precision K/V all-gather.  Local
+            # (SWA) layers take the same path; the window mask bounds useful
+            # work.
+            out = sp_full_attention_spmd(
+                ctx.mesh, q, k, v, causal=causal, window=window, softcap=cap,
+                chunk=ctx.attn_chunk)
+        else:
+            pos = jnp.arange(t)
+            out = full_attention(q, k, v, q_pos=pos, k_pos=pos, causal=causal,
+                                 window=window, softcap=cap)
 
     new_cache = None
     if cache is not None:  # prefill writes the cache
-        new_cache = ctx.backend.prefill_write(
-            cache, k, v, ctx=ctx, kind=kind, vq_params=vq_params,
-            block_tables=block_tables, lengths=lengths)
-    y = out.reshape(b, t, -1) @ params["wo"]
-    return y, aux, new_cache
+        with jax.named_scope("kv_write"):
+            new_cache = ctx.backend.prefill_write(
+                cache, k, v, ctx=ctx, kind=kind, vq_params=vq_params,
+                block_tables=block_tables, lengths=lengths)
+    return out_proj(params, out), aux, new_cache
 
 
 def _zero_aux(cfg) -> Dict[str, jax.Array]:
@@ -189,6 +199,7 @@ def init_attn_cache(cfg, kind: str, batch: int, max_len: int, ctx: StepCtx,
                                   prefill_scratch=prefill_scratch)
 
 
+@jax.named_scope("kv_write")
 def _write_at(buf: jax.Array, new: jax.Array, idx: jax.Array) -> jax.Array:
     """Per-batch dynamic write: buf (B, S, ...), new (B, 1, ...), idx (B,)."""
     def one(b, n, i):
@@ -260,11 +271,11 @@ def _masked_decode_attn(params, q, k_all, v_all, valid, cap) -> jax.Array:
     """Shared single-token decode epilogue: masked partial-softmax stats,
     normalize, project through wo.  Every cache layout funnels through this
     so the cache modes cannot drift numerically."""
-    b = q.shape[0]
-    m, l, o = partial_attention_stats(q, k_all, v_all, k_valid=valid,
-                                      softcap=cap)
-    out = o / jnp.maximum(jnp.moveaxis(l, 1, 2)[..., None], 1e-30)
-    return out.reshape(b, 1, -1) @ params["wo"]
+    with jax.named_scope("attn_kernel"):
+        m, l, o = partial_attention_stats(q, k_all, v_all, k_valid=valid,
+                                          softcap=cap)
+        out = o / jnp.maximum(jnp.moveaxis(l, 1, 2)[..., None], 1e-30)
+    return out_proj(params, out)
 
 
 # ---------------------------------------------------------------------------
@@ -282,10 +293,10 @@ def _pallas_decode_attn(params, q, k_all, v_all, lengths, window,
     ``kernels.vq_decode_attn``)."""
     from repro.kernels import ops
 
-    b = q.shape[0]
-    out = ops.decode_attention(q, k_all, v_all, lengths, window=window,
-                               softcap=cap)
-    return out.reshape(b, 1, -1) @ params["wo"]
+    with jax.named_scope("attn_kernel"):
+        out = ops.decode_attention(q, k_all, v_all, lengths, window=window,
+                                   softcap=cap)
+    return out_proj(params, out)
 
 
 def _pallas_coded_decode_attn(params, q, k_codes, v_codes, vq_params,
@@ -295,11 +306,11 @@ def _pallas_coded_decode_attn(params, q, k_codes, v_codes, vq_params,
     dequantizes the whole cache first)."""
     from repro.kernels import ops
 
-    b = q.shape[0]
-    out = ops.coded_decode_attention(
-        q, k_codes, v_codes, vq_params["k"]["codebook"],
-        vq_params["v"]["codebook"], lengths, softcap=cap)
-    return out.reshape(b, 1, -1) @ params["wo"]
+    with jax.named_scope("attn_kernel"):
+        out = ops.coded_decode_attention(
+            q, k_codes, v_codes, vq_params["k"]["codebook"],
+            vq_params["v"]["codebook"], lengths, softcap=cap)
+    return out_proj(params, out)
 
 
 def _pallas_chunk_attn(params, q, k_all, v_all, chunk_start, k_pos, window,
@@ -310,10 +321,10 @@ def _pallas_chunk_attn(params, q, k_all, v_all, chunk_start, k_pos, window,
     the prefix/ring key-position map."""
     from repro.kernels import ops
 
-    b, wq = q.shape[:2]
-    out = ops.chunk_attention(q, k_all, v_all, k_pos, chunk_start,
-                              causal=True, window=window, softcap=cap)
-    return out.reshape(b, wq, -1) @ params["wo"]
+    with jax.named_scope("attn_kernel"):
+        out = ops.chunk_attention(q, k_all, v_all, k_pos, chunk_start,
+                                  causal=True, window=window, softcap=cap)
+    return out_proj(params, out)
 
 
 def attention_chunk(
@@ -353,20 +364,21 @@ def _masked_chunk_attn(params, q, k_all, v_all, q_pos, k_pos, window,
     window); rows/positions with no valid key (padding queries) normalize
     against an epsilon instead of NaN-ing, exactly like the decode
     epilogue."""
-    b, wq = q.shape[:2]
-    kp = k_pos if k_pos.ndim == 2 else jnp.broadcast_to(
-        k_pos[None], (b, k_pos.shape[-1]))
-    qp = q_pos if q_pos.ndim == 2 else jnp.broadcast_to(
-        q_pos[None], (b, q_pos.shape[-1]))
-    valid = (kp[:, None, :] >= 0) & (kp[:, None, :] <= qp[:, :, None])
-    if window:
-        valid &= kp[:, None, :] > qp[:, :, None] - window
-    scale = 1.0 / jnp.sqrt(q.shape[-1]).astype(jnp.float32)
-    s = _softcap(_gqa_scores(q, k_all, scale), cap)  # (B, H, W, S)
-    s = jnp.where(valid[:, None], s, NEG_INF)
-    m = jnp.max(s, axis=-1, keepdims=True)
-    p = jnp.where(valid[:, None], jnp.exp(s - m), 0.0)
-    l = jnp.sum(p, axis=-1)  # (B, H, W)
-    out = _gqa_combine(p, v_all)  # (B, W, H, hd) un-normalised
-    out = out / jnp.maximum(jnp.moveaxis(l, 1, 2)[..., None], 1e-30)
-    return out.reshape(b, wq, -1) @ params["wo"]
+    with jax.named_scope("attn_kernel"):
+        b = q.shape[0]
+        kp = k_pos if k_pos.ndim == 2 else jnp.broadcast_to(
+            k_pos[None], (b, k_pos.shape[-1]))
+        qp = q_pos if q_pos.ndim == 2 else jnp.broadcast_to(
+            q_pos[None], (b, q_pos.shape[-1]))
+        valid = (kp[:, None, :] >= 0) & (kp[:, None, :] <= qp[:, :, None])
+        if window:
+            valid &= kp[:, None, :] > qp[:, :, None] - window
+        scale = 1.0 / jnp.sqrt(q.shape[-1]).astype(jnp.float32)
+        s = _softcap(_gqa_scores(q, k_all, scale), cap)  # (B, H, W, S)
+        s = jnp.where(valid[:, None], s, NEG_INF)
+        m = jnp.max(s, axis=-1, keepdims=True)
+        p = jnp.where(valid[:, None], jnp.exp(s - m), 0.0)
+        l = jnp.sum(p, axis=-1)  # (B, H, W)
+        out = _gqa_combine(p, v_all)  # (B, W, H, hd) un-normalised
+        out = out / jnp.maximum(jnp.moveaxis(l, 1, 2)[..., None], 1e-30)
+    return out_proj(params, out)

@@ -197,11 +197,12 @@ def block_forward(
             y = apply_norm(p["post1"], y, cfg.norm)
         x = x + y.astype(x.dtype)
         h2 = apply_norm(p["norm2"], x, cfg.norm)
-        if cfg.moe is not None:
-            y2, moe_aux = moe_mod.apply_moe(p["moe"], h2, cfg, ctx)
-            aux["moe_aux"] = moe_aux
-        else:
-            y2 = apply_mlp(p["mlp"], h2, cfg.activation)
+        with jax.named_scope("mlp"):
+            if cfg.moe is not None:
+                y2, moe_aux = moe_mod.apply_moe(p["moe"], h2, cfg, ctx)
+                aux["moe_aux"] = moe_aux
+            else:
+                y2 = apply_mlp(p["mlp"], h2, cfg.activation)
         if cfg.post_norm:
             y2 = apply_norm(p["post2"], y2, cfg.norm)
         return x + y2.astype(x.dtype), aux, new_navq, new_cache
@@ -216,7 +217,8 @@ def block_forward(
                                                   start=chunk_start)
         x = x + y.astype(x.dtype)
         h2 = apply_norm(p["norm2"], x, cfg.norm)
-        y2 = apply_mlp(p["mlp"], h2, cfg.activation)
+        with jax.named_scope("mlp"):
+            y2 = apply_mlp(p["mlp"], h2, cfg.activation)
         return x + y2.astype(x.dtype), aux, new_navq, new_cache
 
     if kind == "ssm":
@@ -315,6 +317,7 @@ def init_lm_cache(cfg, batch: int, max_len: int, ctx: StepCtx,
 # ---------------------------------------------------------------------------
 
 
+@jax.named_scope("embed")
 def _embed_inputs(params, batch: Dict, cfg) -> jax.Array:
     tokens = batch["tokens"]
     x = jnp.take(params["embed"], tokens, axis=0)
@@ -406,18 +409,19 @@ def lm_forward(
         params["stages"], x, ctx=ctx, cfg=cfg, causal=True, rng=rng,
         navq_state=navq_state, caches=caches, lengths=lengths,
         block_tables=block_tables)
-    x = apply_norm(params["final_norm"], x, cfg.norm)
-    if ctx.logits_last_only:
-        # §Perf: prefill only needs the next-token distribution — skip the
-        # (B, T, vocab) logits matmul for all but the final position.
-        x = x[:, -1:]
-    head = params["lm_head"] if "lm_head" in params else params["embed"].T
-    logits = (x @ head.astype(x.dtype)).astype(jnp.float32)
-    if ctx.seq_sharded and not ctx.logits_last_only:
-        from repro.core.sequence_parallel import constrain_seq_sharded
+    with jax.named_scope("head"):
+        x = apply_norm(params["final_norm"], x, cfg.norm)
+        if ctx.logits_last_only:
+            # §Perf: prefill only needs the next-token distribution — skip
+            # the (B, T, vocab) logits matmul for all but the final position.
+            x = x[:, -1:]
+        head = params["lm_head"] if "lm_head" in params else params["embed"].T
+        logits = (x @ head.astype(x.dtype)).astype(jnp.float32)
+        if ctx.seq_sharded and not ctx.logits_last_only:
+            from repro.core.sequence_parallel import constrain_seq_sharded
 
-        logits = constrain_seq_sharded(logits, ctx.mesh)
-    logits = softcap(logits, cfg.final_logit_softcap)
+            logits = constrain_seq_sharded(logits, ctx.mesh)
+        logits = softcap(logits, cfg.final_logit_softcap)
     return logits, aux, new_navq, new_caches
 
 
@@ -445,27 +449,32 @@ def lm_prefill_chunk(
     """
     cfg = ctx.cfg
     b, w = tokens.shape
-    x = jnp.take(params["embed"], tokens, axis=0)
-    if "pos_embed" in params:
-        # per-position clipped gather: only bucket-overhang positions (junk
-        # past every row's prompt) clamp — a clamped contiguous slice would
-        # shift the embeddings of the *real* tokens in the tail chunk
-        pos = jnp.clip(chunk_start + jnp.arange(w), 0, cfg.max_seq_len - 1)
-        x = x + jnp.take(params["pos_embed"], pos, axis=0)[None]
-    x = x.astype(_adtype(cfg, ctx))
+    with jax.named_scope("embed"):
+        x = jnp.take(params["embed"], tokens, axis=0)
+        if "pos_embed" in params:
+            # per-position clipped gather: only bucket-overhang positions
+            # (junk past every row's prompt) clamp — a clamped contiguous
+            # slice would shift the embeddings of the *real* tokens in the
+            # tail chunk
+            pos = jnp.clip(chunk_start + jnp.arange(w), 0,
+                           cfg.max_seq_len - 1)
+            x = x + jnp.take(params["pos_embed"], pos, axis=0)[None]
+        x = x.astype(_adtype(cfg, ctx))
     x, _, _, new_caches = run_stages(
         params["stages"], x, ctx=ctx, cfg=cfg, causal=True, rng=None,
         navq_state=None, caches=caches, lengths=lengths,
         block_tables=block_tables, chunk_start=chunk_start,
         history_len=history_len)
-    idx = jnp.clip(lengths - 1 - chunk_start, 0, w - 1)
-    xl = jnp.take_along_axis(x, idx[:, None, None], axis=1)  # (B, 1, D)
-    xl = apply_norm(params["final_norm"], xl, cfg.norm)
-    head = params["lm_head"] if "lm_head" in params else params["embed"].T
-    logits = _head_matmul(xl, head, cfg, ctx)[:, 0]
-    logits = softcap(logits, cfg.final_logit_softcap)
-    ends_here = (lengths - 1 >= chunk_start) & (lengths - 1 < chunk_start + w)
-    last_logits = jnp.where(ends_here[:, None], logits, last_logits)
+    with jax.named_scope("head"):
+        idx = jnp.clip(lengths - 1 - chunk_start, 0, w - 1)
+        xl = jnp.take_along_axis(x, idx[:, None, None], axis=1)  # (B, 1, D)
+        xl = apply_norm(params["final_norm"], xl, cfg.norm)
+        head = params["lm_head"] if "lm_head" in params else params["embed"].T
+        logits = _head_matmul(xl, head, cfg, ctx)[:, 0]
+        logits = softcap(logits, cfg.final_logit_softcap)
+        ends_here = ((lengths - 1 >= chunk_start)
+                     & (lengths - 1 < chunk_start + w))
+        last_logits = jnp.where(ends_here[:, None], logits, last_logits)
     return last_logits, new_caches
 
 
@@ -507,6 +516,7 @@ def _head_matmul(x: jax.Array, head: jax.Array, cfg, ctx: StepCtx
     return _constrain(logits, mesh, P(bspec, None, None))
 
 
+@jax.named_scope("embed")
 def _decode_embed(params: Dict, token: jax.Array, lengths: jax.Array,
                   ctx: StepCtx) -> jax.Array:
     """Decode-step input embeddings (B, 1, D).
@@ -560,13 +570,15 @@ def lm_decode_step(
         params["stages"], x, ctx=ctx, cfg=cfg, causal=True, rng=None,
         navq_state=None, caches=caches, lengths=lengths,
         block_tables=block_tables)
-    x = apply_norm(params["final_norm"], x, cfg.norm)
-    head = params["lm_head"] if "lm_head" in params else params["embed"].T
-    logits = _head_matmul(x, head, cfg, ctx)
-    logits = softcap(logits, cfg.final_logit_softcap)
+    with jax.named_scope("head"):
+        x = apply_norm(params["final_norm"], x, cfg.norm)
+        head = params["lm_head"] if "lm_head" in params else params["embed"].T
+        logits = _head_matmul(x, head, cfg, ctx)
+        logits = softcap(logits, cfg.final_logit_softcap)
     return logits, new_caches
 
 
+@jax.named_scope("embed")
 def _verify_embed(params: Dict, tokens: jax.Array, starts: jax.Array,
                   ctx: StepCtx) -> jax.Array:
     """Verify-step input embeddings (B, W, D) at per-row positions
@@ -620,10 +632,12 @@ def lm_verify_chunk(
         params["stages"], x, ctx=ctx, cfg=cfg, causal=True, rng=None,
         navq_state=None, caches=caches, lengths=lengths,
         block_tables=block_tables, verify_starts=lengths)
-    x = apply_norm(params["final_norm"], x, cfg.norm)
-    head = params["lm_head"] if "lm_head" in params else params["embed"].T
-    logits = _head_matmul(x, head, cfg, ctx)
-    return softcap(logits, cfg.final_logit_softcap), new_caches
+    with jax.named_scope("head"):
+        x = apply_norm(params["final_norm"], x, cfg.norm)
+        head = params["lm_head"] if "lm_head" in params else params["embed"].T
+        logits = softcap(_head_matmul(x, head, cfg, ctx),
+                         cfg.final_logit_softcap)
+    return logits, new_caches
 
 
 def lm_rollback_caches(

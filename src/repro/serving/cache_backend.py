@@ -143,6 +143,7 @@ def _slab_prefill_fp(cache, k, v, lengths=None):
     return {"k": ck, "v": cv}
 
 
+@jax.named_scope("kv_write")
 def _chunk_slab_write(buf: jax.Array, vals: jax.Array,
                       chunk_start: jax.Array) -> jax.Array:
     """Write a chunk (B, W, ...) at positions ``chunk_start .. +W-1`` of a
@@ -161,6 +162,7 @@ def _verify_positions(starts: jax.Array, w: int) -> jax.Array:
     return starts[:, None] + jnp.arange(w)[None, :]
 
 
+@jax.named_scope("kv_write")
 def _slab_verify_write(bk: jax.Array, bv: jax.Array, k_new: jax.Array,
                        v_new: jax.Array, starts: jax.Array):
     """Per-row scatter of W verify tokens into (B, S) slabs at positions
@@ -226,6 +228,7 @@ def _ring_chunk_sources(s: int, chunk_start: jax.Array, lengths: jax.Array,
     return take, src
 
 
+@jax.named_scope("kv_write")
 def _ring_chunk_write(cache: Dict, k: jax.Array, v: jax.Array,
                       chunk_start: jax.Array, lengths: jax.Array) -> Dict:
     """Masked keep-latest chunk write into a dense (B, S) ring slab."""
@@ -313,6 +316,28 @@ def _table_for(block_tables, kind: str, cfg) -> jax.Array:
     if isinstance(block_tables, dict):
         return block_tables[kvc.page_group_for(kind, cfg)]
     return block_tables  # single pre-selected table
+
+
+@jax.named_scope("kv_write")
+def _pool_write(kp: jax.Array, vp: jax.Array, dest: jax.Array,
+                offs: jax.Array, k: jax.Array, v: jax.Array):
+    """Token-granular write into a K and a V page pool (N, ps, ...): token
+    ``i`` of ``k``/``v`` (shaped ``dest.shape + (...)``) lands on page
+    ``dest[i]`` at offset ``offs[i]``."""
+    n = dest.ndim
+    idx = (dest.reshape(-1), offs.reshape(-1))
+    return (kp.at[idx].set(k.reshape((-1,) + k.shape[n:]).astype(kp.dtype)),
+            vp.at[idx].set(v.reshape((-1,) + v.shape[n:]).astype(vp.dtype)))
+
+
+@jax.named_scope("page_gather")
+def _pool_view(kp: jax.Array, vp: jax.Array, table: jax.Array):
+    """Gather each row's pages through ``table`` (B, n) into contiguous
+    (B, n * ps, ...) K and V views."""
+    b, n = table.shape
+    ps = kp.shape[1]
+    return (kp[table].reshape((b, n * ps) + kp.shape[2:]),
+            vp[table].reshape((b, n * ps) + vp.shape[2:]))
 
 
 def _scatter_pages(pool: jax.Array, vals: jax.Array, table: jax.Array,
@@ -808,21 +833,18 @@ class PagedBackend(CacheBackend):
         kp = cache["k_code_pages" if vq_pool else "k_pages"]
         vp = cache["v_code_pages" if vq_pool else "v_pages"]
         ps = kp.shape[1]
-        b = k_new.shape[0]
         s = table.shape[1] * ps  # ring length (== max_len for global tables)
         flat = jnp.mod(lengths, s)
         page_ids = jnp.take_along_axis(table, (flat // ps)[:, None],
                                        axis=1)[:, 0]
         offs = jnp.mod(flat, ps)
         if vq_pool:
-            kc, vc, spec = _encode_pair(k_new, v_new, cfg, vq_params)
-            kp = kp.at[page_ids, offs].set(kc[:, 0].astype(kp.dtype))
-            vp = vp.at[page_ids, offs].set(vc[:, 0].astype(vp.dtype))
+            kc, vc, _ = _encode_pair(k_new, v_new, cfg, vq_params)
+            kp, vp = _pool_write(kp, vp, page_ids, offs, kc[:, 0], vc[:, 0])
             new_cache = {"k_code_pages": kp, "v_code_pages": vp}
             # gather code pages into one contiguous (B, s, G) tile — the
             # kernels never see a block table, only block-aligned tiles
-            codes_k = kp[table].reshape(b, s, spec.groups)
-            codes_v = vp[table].reshape(b, s, spec.groups)
+            codes_k, codes_v = _pool_view(kp, vp, table)
             if ctx.use_pallas and not window and _coded_kernel_ok(cfg):
                 y = attn._pallas_coded_decode_attn(params, q, codes_k,
                                                    codes_v, vq_params,
@@ -831,10 +853,9 @@ class PagedBackend(CacheBackend):
             k_all = _decode_codes(codes_k, cfg, vq_params, "k")
             v_all = _decode_codes(codes_v, cfg, vq_params, "v")
         else:
-            kp = kp.at[page_ids, offs].set(k_new[:, 0].astype(kp.dtype))
-            vp = vp.at[page_ids, offs].set(v_new[:, 0].astype(vp.dtype))
-            k_all = kp[table].reshape((b, s) + kp.shape[2:])
-            v_all = vp[table].reshape((b, s) + vp.shape[2:])
+            kp, vp = _pool_write(kp, vp, page_ids, offs, k_new[:, 0],
+                                 v_new[:, 0])
+            k_all, v_all = _pool_view(kp, vp, table)
             new_cache = {"k_pages": kp, "v_pages": vp}
         if ctx.use_pallas:
             # the gathered view is a ring over the table span; the kernel's
@@ -868,8 +889,7 @@ class PagedBackend(CacheBackend):
         q_pos = chunk_start + jnp.arange(w)
 
         if window:  # fp page ring (windowed layers keep fp pages under vq)
-            ring_k = kp[table].reshape((b, s) + kp.shape[2:])
-            ring_v = vp[table].reshape((b, s) + vp.shape[2:])
+            ring_k, ring_v = _pool_view(kp, vp, table)
             k_pos = _ring_k_pos(s, chunk_start, w)
             k_all = jnp.concatenate([ring_k.astype(k_new.dtype), k_new], 1)
             v_all = jnp.concatenate([ring_v.astype(v_new.dtype), v_new], 1)
@@ -887,10 +907,7 @@ class PagedBackend(CacheBackend):
             gv = jnp.take_along_axis(v_new, idx, axis=1)
             dest = jnp.where(take, table[:, np.arange(s) // ps], 0)
             offs = jnp.broadcast_to(np.arange(s) % ps, (b, s))
-            kp = kp.at[dest.reshape(-1), offs.reshape(-1)].set(
-                gk.reshape((b * s,) + gk.shape[2:]).astype(kp.dtype))
-            vp = vp.at[dest.reshape(-1), offs.reshape(-1)].set(
-                gv.reshape((b * s,) + gv.shape[2:]).astype(vp.dtype))
+            kp, vp = _pool_write(kp, vp, dest, offs, gk, gv)
             return y, {"k_pages": kp, "v_pages": vp}
 
         # global table: scatter the chunk token-granular (positions past the
@@ -901,10 +918,7 @@ class PagedBackend(CacheBackend):
         if vq_pool:
             _require_scratch(cache, self.name)
             kc, vc, _ = _encode_pair(k_new, v_new, cfg, vq_params)
-            kp = kp.at[dest.reshape(-1), offs.reshape(-1)].set(
-                kc.reshape((b * w,) + kc.shape[2:]).astype(kp.dtype))
-            vp = vp.at[dest.reshape(-1), offs.reshape(-1)].set(
-                vc.reshape((b * w,) + vc.shape[2:]).astype(vp.dtype))
+            kp, vp = _pool_write(kp, vp, dest, offs, kc, vc)
             k_view = _chunk_slab_write(cache["k_fp"], k_new, chunk_start)
             v_view = _chunk_slab_write(cache["v_fp"], v_new, chunk_start)
             hv = _view_len(k_view.shape[1], history_len)
@@ -912,18 +926,14 @@ class PagedBackend(CacheBackend):
                                  chunk_start, hv, cap, ctx)
             return y, {"k_code_pages": kp, "v_code_pages": vp,
                        "k_fp": k_view, "v_fp": v_view}
-        kp = kp.at[dest.reshape(-1), offs.reshape(-1)].set(
-            k_new.reshape((b * w,) + k_new.shape[2:]).astype(kp.dtype))
-        vp = vp.at[dest.reshape(-1), offs.reshape(-1)].set(
-            v_new.reshape((b * w,) + v_new.shape[2:]).astype(vp.dtype))
+        kp, vp = _pool_write(kp, vp, dest, offs, k_new, v_new)
         # gather only the first ceil(hv/ps) pages per row — the view length
         # ladder keeps both the gather (a block-aligned contiguous tile the
         # kernel can consume) and the score matrix prompt-sized
         hv = _view_len(s, history_len)
         n_view = -(-hv // ps)
         sv = n_view * ps
-        k_all = kp[table[:, :n_view]].reshape((b, sv) + kp.shape[2:])
-        v_all = vp[table[:, :n_view]].reshape((b, sv) + vp.shape[2:])
+        k_all, v_all = _pool_view(kp, vp, table[:, :n_view])
         y = _view_chunk_attn(params, q, k_all, v_all, chunk_start, sv, cap,
                              ctx)
         return y, {"k_pages": kp, "v_pages": vp}
@@ -947,7 +957,7 @@ class PagedBackend(CacheBackend):
         kp = cache["k_code_pages" if vq_pool else "k_pages"]
         vp = cache["v_code_pages" if vq_pool else "v_pages"]
         ps = kp.shape[1]
-        b, w = k_new.shape[:2]
+        w = k_new.shape[1]
         s = table.shape[1] * ps  # == max_len for global tables
         pos = _verify_positions(starts, w)
         page_idx = jnp.clip(pos // ps, 0, table.shape[1] - 1)
@@ -955,14 +965,10 @@ class PagedBackend(CacheBackend):
                          jnp.take_along_axis(table, page_idx, axis=1), 0)
         offs = jnp.mod(pos, ps)
         if vq_pool:
-            kc, vc, spec = _encode_pair(k_new, v_new, cfg, vq_params)
-            kp = kp.at[dest.reshape(-1), offs.reshape(-1)].set(
-                kc.reshape((b * w,) + kc.shape[2:]).astype(kp.dtype))
-            vp = vp.at[dest.reshape(-1), offs.reshape(-1)].set(
-                vc.reshape((b * w,) + vc.shape[2:]).astype(vp.dtype))
+            kc, vc, _ = _encode_pair(k_new, v_new, cfg, vq_params)
+            kp, vp = _pool_write(kp, vp, dest, offs, kc, vc)
             new_cache = {"k_code_pages": kp, "v_code_pages": vp}
-            codes_k = kp[table].reshape(b, s, spec.groups)
-            codes_v = vp[table].reshape(b, s, spec.groups)
+            codes_k, codes_v = _pool_view(kp, vp, table)
             if ctx.use_pallas and _coded_kernel_ok(cfg):
                 ys = [attn._pallas_coded_decode_attn(
                           params, q[:, j:j + 1], codes_k, codes_v,
@@ -971,13 +977,9 @@ class PagedBackend(CacheBackend):
             k_all = _decode_codes(codes_k, cfg, vq_params, "k")
             v_all = _decode_codes(codes_v, cfg, vq_params, "v")
         else:
-            kp = kp.at[dest.reshape(-1), offs.reshape(-1)].set(
-                k_new.reshape((b * w,) + k_new.shape[2:]).astype(kp.dtype))
-            vp = vp.at[dest.reshape(-1), offs.reshape(-1)].set(
-                v_new.reshape((b * w,) + v_new.shape[2:]).astype(vp.dtype))
+            kp, vp = _pool_write(kp, vp, dest, offs, k_new, v_new)
             new_cache = {"k_pages": kp, "v_pages": vp}
-            k_all = kp[table].reshape((b, s) + kp.shape[2:])
-            v_all = vp[table].reshape((b, s) + vp.shape[2:])
+            k_all, v_all = _pool_view(kp, vp, table)
         if ctx.use_pallas:
             y = _unrolled_pallas_verify(params, q, k_all, v_all, starts, 0,
                                         cap)
@@ -1002,14 +1004,11 @@ class PagedBackend(CacheBackend):
         s = table.shape[1] * ps
         p = attn.ring_positions(s, starts + num_tokens - 1)  # (B, s)
         mask = p >= (starts + accepted)[:, None]
-        old_k = old_cache["k_pages"][table].reshape((b, s) + kp.shape[2:])
-        old_v = old_cache["v_pages"][table].reshape((b, s) + vp.shape[2:])
+        old_k, old_v = _pool_view(old_cache["k_pages"], old_cache["v_pages"],
+                                  table)
         dest = jnp.where(mask, table[:, np.arange(s) // ps], 0)
         offs = jnp.broadcast_to(np.arange(s) % ps, (b, s))
-        kp = kp.at[dest.reshape(-1), offs.reshape(-1)].set(
-            old_k.reshape((b * s,) + old_k.shape[2:]).astype(kp.dtype))
-        vp = vp.at[dest.reshape(-1), offs.reshape(-1)].set(
-            old_v.reshape((b * s,) + old_v.shape[2:]).astype(vp.dtype))
+        kp, vp = _pool_write(kp, vp, dest, offs, old_k, old_v)
         return {"k_pages": kp, "v_pages": vp}
 
     def make_state(self, cfg, *, slots, max_len, ctx, dtype=None,
@@ -1262,15 +1261,19 @@ def _decode_sharded(params, q, k_new, v_new, cache, lengths, ctx, cfg, cap,
         cb_k = cb_v = jnp.zeros((1,), jnp.float32)
         ck_in, cv_in = cache["k"], cache["v"]
 
-    out, ck2, cv2 = shard_map(
-        body, mesh=ctx.mesh.mesh, in_specs=in_specs, out_specs=out_specs,
-        check_vma=False)(q, k_new, v_new, ck_in, cv_in, lengths, cb_k, cb_v)
-    y = out.reshape(b, 1, -1) @ params["wo"]
+    with jax.named_scope("attn_kernel"):
+        out, ck2, cv2 = shard_map(
+            body, mesh=ctx.mesh.mesh, in_specs=in_specs, out_specs=out_specs,
+            check_vma=False)(q, k_new, v_new, ck_in, cv_in, lengths, cb_k,
+                             cb_v)
+    with jax.named_scope("attn_out"):
+        y = out.reshape(b, 1, -1) @ params["wo"]
     new_cache = ({"k_codes": ck2, "v_codes": cv2} if vq_cache
                  else {"k": ck2, "v": cv2})
     return y, new_cache
 
 
+@jax.named_scope("kv_write")
 def _shard_chunk_write(buf: jax.Array, vals: jax.Array,
                        loc_pos: jax.Array) -> jax.Array:
     """Write a chunk (B, W, ...) into a shard-local (B, S_loc, ...) slab at
@@ -1368,11 +1371,13 @@ def _chunk_sharded(params, q, k_new, v_new, cache, chunk_start, ctx, cfg,
         ck_in, cv_in = cache["k"], cache["v"]
         kf_in = vf_in = jnp.zeros((1,), jnp.float32)
 
-    out, ck2, cv2, kf2, vf2 = shard_map(
-        body, mesh=ctx.mesh.mesh, in_specs=in_specs, out_specs=out_specs,
-        check_vma=False)(q, k_new, v_new, ck_in, cv_in, kf_in, vf_in, cs,
-                         cb_k, cb_v)
-    y = out.reshape(b, w, -1) @ params["wo"]
+    with jax.named_scope("attn_kernel"):
+        out, ck2, cv2, kf2, vf2 = shard_map(
+            body, mesh=ctx.mesh.mesh, in_specs=in_specs, out_specs=out_specs,
+            check_vma=False)(q, k_new, v_new, ck_in, cv_in, kf_in, vf_in, cs,
+                             cb_k, cb_v)
+    with jax.named_scope("attn_out"):
+        y = out.reshape(b, w, -1) @ params["wo"]
     new_cache = ({"k_codes": ck2, "v_codes": cv2, "k_fp": kf2, "v_fp": vf2}
                  if vq_cache else {"k": ck2, "v": cv2})
     return y, new_cache
@@ -1436,10 +1441,8 @@ def _paged_decode_sharded(params, q, k_new, v_new, cache, lengths, table,
                              cfg.astra.codebook_size)
             kc = vq.encode({"codebook": cb_k}, k_n.reshape(bl, 1, -1), spec)
             vc = vq.encode({"codebook": cb_v}, v_n.reshape(bl, 1, -1), spec)
-            kp2 = kp.at[dest, offs].set(kc[:, 0].astype(kp.dtype))
-            vp2 = vp.at[dest, offs].set(vc[:, 0].astype(vp.dtype))
-            codes_k = kp2[loc_ids].reshape(bl, s_loc, spec.groups)
-            codes_v = vp2[loc_ids].reshape(bl, s_loc, spec.groups)
+            kp2, vp2 = _pool_write(kp, vp, dest, offs, kc[:, 0], vc[:, 0])
+            codes_k, codes_v = _pool_view(kp2, vp2, loc_ids)
             if kernel_ok:
                 from repro.kernels.ops import decode_attention_partials
 
@@ -1456,10 +1459,8 @@ def _paged_decode_sharded(params, q, k_new, v_new, cache, lengths, table,
                                 codes_v.astype(jnp.int32), spec).reshape(
                 bl, s_loc, cfg.num_kv_heads, cfg.head_dim)
         else:
-            kp2 = kp.at[dest, offs].set(k_n[:, 0].astype(kp.dtype))
-            vp2 = vp.at[dest, offs].set(v_n[:, 0].astype(vp.dtype))
-            k_shard = kp2[loc_ids].reshape((bl, s_loc) + kp.shape[2:])
-            v_shard = vp2[loc_ids].reshape((bl, s_loc) + vp.shape[2:])
+            kp2, vp2 = _pool_write(kp, vp, dest, offs, k_n[:, 0], v_n[:, 0])
+            k_shard, v_shard = _pool_view(kp2, vp2, loc_ids)
         if pallas_on:
             from repro.kernels.ops import fp_decode_partials
 
@@ -1486,11 +1487,13 @@ def _paged_decode_sharded(params, q, k_new, v_new, cache, lengths, table,
         cb_v = vq_params["v"]["codebook"]
     else:
         cb_k = cb_v = jnp.zeros((1,), jnp.float32)
-    out, kp2, vp2 = shard_map(
-        body, mesh=ctx.mesh.mesh, in_specs=in_specs, out_specs=out_specs,
-        check_vma=False)(q, k_new, v_new, kp_in, vp_in, table, lengths,
-                         cb_k, cb_v)
-    y = out.reshape(b, 1, -1) @ params["wo"]
+    with jax.named_scope("attn_kernel"):
+        out, kp2, vp2 = shard_map(
+            body, mesh=ctx.mesh.mesh, in_specs=in_specs, out_specs=out_specs,
+            check_vma=False)(q, k_new, v_new, kp_in, vp_in, table, lengths,
+                             cb_k, cb_v)
+    with jax.named_scope("attn_out"):
+        y = out.reshape(b, 1, -1) @ params["wo"]
     new_cache = ({"k_code_pages": kp2, "v_code_pages": vp2} if vq_pool
                  else {"k_pages": kp2, "v_pages": vp2})
     return y, new_cache
@@ -1533,21 +1536,14 @@ def _paged_chunk_sharded(params, q, k_new, v_new, cache, chunk_start, table,
                              cfg.astra.codebook_size)
             kc = vq.encode({"codebook": cb_k}, k_n.reshape(bl, w, -1), spec)
             vc = vq.encode({"codebook": cb_v}, v_n.reshape(bl, w, -1), spec)
-            kp2 = kp.at[dest.reshape(-1), offs.reshape(-1)].set(
-                kc.reshape((bl * w,) + kc.shape[2:]).astype(kp.dtype))
-            vp2 = vp.at[dest.reshape(-1), offs.reshape(-1)].set(
-                vc.reshape((bl * w,) + vc.shape[2:]).astype(vp.dtype))
+            kp2, vp2 = _pool_write(kp, vp, dest, offs, kc, vc)
             kf2 = _shard_chunk_write(kf, k_n, loc_pos)
             vf2 = _shard_chunk_write(vf, v_n, loc_pos)
             k_view, v_view = kf2, vf2
         else:
-            kp2 = kp.at[dest.reshape(-1), offs.reshape(-1)].set(
-                k_n.reshape((bl * w,) + k_n.shape[2:]).astype(kp.dtype))
-            vp2 = vp.at[dest.reshape(-1), offs.reshape(-1)].set(
-                v_n.reshape((bl * w,) + v_n.shape[2:]).astype(vp.dtype))
+            kp2, vp2 = _pool_write(kp, vp, dest, offs, k_n, v_n)
             kf2, vf2 = kf, vf
-            k_view = kp2[loc_ids].reshape((bl, s_loc) + kp.shape[2:])
-            v_view = vp2[loc_ids].reshape((bl, s_loc) + vp.shape[2:])
+            k_view, v_view = _pool_view(kp2, vp2, loc_ids)
         out = _chunk_shard_merge(q_l, k_view, v_view, cs_l, off, cap, axis,
                                  pallas_on)
         return out, kp2, vp2, kf2, vf2
@@ -1570,11 +1566,13 @@ def _paged_chunk_sharded(params, q, k_new, v_new, cache, chunk_start, table,
         cb_k = cb_v = jnp.zeros((1,), jnp.float32)
         kf_in = vf_in = jnp.zeros((1,), jnp.float32)
 
-    out, kp2, vp2, kf2, vf2 = shard_map(
-        body, mesh=ctx.mesh.mesh, in_specs=in_specs, out_specs=out_specs,
-        check_vma=False)(q, k_new, v_new, kp_in, vp_in, kf_in, vf_in, table,
-                         cs, cb_k, cb_v)
-    y = out.reshape(b, w, -1) @ params["wo"]
+    with jax.named_scope("attn_kernel"):
+        out, kp2, vp2, kf2, vf2 = shard_map(
+            body, mesh=ctx.mesh.mesh, in_specs=in_specs, out_specs=out_specs,
+            check_vma=False)(q, k_new, v_new, kp_in, vp_in, kf_in, vf_in,
+                             table, cs, cb_k, cb_v)
+    with jax.named_scope("attn_out"):
+        y = out.reshape(b, w, -1) @ params["wo"]
     new_cache = ({"k_code_pages": kp2, "v_code_pages": vp2, "k_fp": kf2,
                   "v_fp": vf2} if vq_pool
                  else {"k_pages": kp2, "v_pages": vp2})

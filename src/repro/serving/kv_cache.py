@@ -329,16 +329,17 @@ def merge_slot(live: List[Dict], fresh: List[Dict], slot) -> List[Dict]:
             jnp.asarray(slot), axis=1)
 
     out = []
-    for l_stage, f_stage in zip(live, fresh):
-        sub = {}
-        for name, l_sub in l_stage.items():
-            f_sub = f_stage.get(name)
-            if is_paged_sub(l_sub):
-                sub[name] = (f_sub if f_sub is not None
-                             and is_paged_sub(f_sub) else l_sub)
-            else:
-                sub[name] = jax.tree.map(one, l_sub, f_sub)
-        out.append(sub)
+    with jax.named_scope("slot_merge"):
+        for l_stage, f_stage in zip(live, fresh):
+            sub = {}
+            for name, l_sub in l_stage.items():
+                f_sub = f_stage.get(name)
+                if is_paged_sub(l_sub):
+                    sub[name] = (f_sub if f_sub is not None
+                                 and is_paged_sub(f_sub) else l_sub)
+                else:
+                    sub[name] = jax.tree.map(one, l_sub, f_sub)
+            out.append(sub)
     return out
 
 

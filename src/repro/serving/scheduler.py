@@ -87,6 +87,18 @@ chunk boundaries instead of after every token.
   memory, but a prefill-vs-decode numeric path difference means it only
   promises completion, not bitwise parity.  Preemption is refused under a
   sequence-sharded mesh (``backend.preemptible``).
+
+**Spans and stamps.**  Each ``step()`` is a ``jax.profiler.TraceAnnotation``
+span ``engine.step`` holding ``engine.admit`` (with ``engine.prefill_chunk``
+-- one chunk's dispatch plus the slot merge -- and ``engine.first_token``,
+the sampling and its blocking fetch), ``engine.decode_dispatch``,
+``engine.sync`` (the ``device_get`` of the chunk's tokens) and
+``engine.emit`` (token bookkeeping, retirement, table rebuild).
+``engine.submit``, ``engine.preempt`` and ``engine.restore`` are spans of
+their own; spans of one request carry its ``uid``.  They record only
+while a profiler trace is active.  ``Request.t_submit`` / ``t_admit`` /
+``t_first`` / ``t_done`` are always stamped, on ``time.perf_counter()``;
+``run_until_drained`` reports TTFT and end-to-end latency in ms from them.
 """
 from __future__ import annotations
 
@@ -97,6 +109,7 @@ from typing import Any, Dict, List, Optional, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.configs.base import ModelConfig
 from repro.core.sequence_parallel import LOCAL, MeshContext
@@ -128,6 +141,13 @@ class Request:
     first_token_step: int = -1
     done_step: int = -1
     preemptions: int = 0
+    # host stamps on time.perf_counter(): submitted, first left the queue
+    # (a preempted request keeps its first admission), first token
+    # sampled, finished
+    t_submit: Optional[float] = None
+    t_admit: Optional[float] = None
+    t_first: Optional[float] = None
+    t_done: Optional[float] = None
 
 
 @dataclasses.dataclass
@@ -365,37 +385,40 @@ class ContinuousBatchingEngine:
         ``priority``: class number, lower = more urgent (default 1).
         ``deadline``: optional TTFT SLO in scheduler steps; orders
         admission within a class (EDF) and feeds goodput accounting."""
-        prompt = list(prompt)
-        if not prompt:
-            raise ValueError("empty prompt")
-        max_new_tokens = int(max_new_tokens)
-        if max_new_tokens <= 0:
-            raise ValueError(
-                f"max_new_tokens must be >= 1, got {max_new_tokens} — the "
-                f"request could never emit and would pin its slot forever")
-        if int(priority) < 0:
-            raise ValueError(f"priority must be >= 0, got {priority}")
-        if deadline is not None:
-            deadline = float(deadline)
-            if not deadline > 0:  # rejects <= 0 and NaN in one comparison
+        with TraceAnnotation("engine.submit", uid=self._uid + 1):
+            prompt = list(prompt)
+            if not prompt:
+                raise ValueError("empty prompt")
+            max_new_tokens = int(max_new_tokens)
+            if max_new_tokens <= 0:
                 raise ValueError(
-                    f"deadline must be a positive number of scheduler "
-                    f"steps, got {deadline}")
-        if len(prompt) + max_new_tokens > self.max_len:
-            raise ValueError(
-                f"prompt length {len(prompt)} + max_new_tokens "
-                f"{max_new_tokens} exceeds max_len={self.max_len}")
-        tokens_needed = len(prompt) + max_new_tokens
-        if not self.kv.can_ever_fit(tokens_needed):
-            raise ValueError(
-                f"request needs pages for {tokens_needed} tokens but "
-                f"the pool can never hold them")
-        self._uid += 1
-        self.queue.append(Request(self._uid, prompt, max_new_tokens,
-                                  eos_id, priority=int(priority),
-                                  deadline=deadline,
-                                  submitted_step=self.step_count))
-        return self._uid
+                    f"max_new_tokens must be >= 1, got {max_new_tokens} — "
+                    f"the request could never emit and would pin its slot "
+                    f"forever")
+            if int(priority) < 0:
+                raise ValueError(f"priority must be >= 0, got {priority}")
+            if deadline is not None:
+                deadline = float(deadline)
+                if not deadline > 0:  # rejects <= 0 and NaN in one comparison
+                    raise ValueError(
+                        f"deadline must be a positive number of scheduler "
+                        f"steps, got {deadline}")
+            if len(prompt) + max_new_tokens > self.max_len:
+                raise ValueError(
+                    f"prompt length {len(prompt)} + max_new_tokens "
+                    f"{max_new_tokens} exceeds max_len={self.max_len}")
+            tokens_needed = len(prompt) + max_new_tokens
+            if not self.kv.can_ever_fit(tokens_needed):
+                raise ValueError(
+                    f"request needs pages for {tokens_needed} tokens but "
+                    f"the pool can never hold them")
+            self._uid += 1
+            self.queue.append(Request(self._uid, prompt, max_new_tokens,
+                                      eos_id, priority=int(priority),
+                                      deadline=deadline,
+                                      submitted_step=self.step_count,
+                                      t_submit=time.perf_counter()))
+            return self._uid
 
     def _slot_tables(self, slot: int):
         if self._bt is None:
@@ -462,21 +485,22 @@ class ContinuousBatchingEngine:
         req = self.active[slot]
         if req is None:
             raise ValueError(f"slot {slot} has no active request")
-        if self.preempt_mode == "swap":
-            entry = self.backend.swap_out(self.kv, slot, self.caches)
-            entry.uid = req.uid
-            ln, ct = jax.device_get((self.lengths[slot],
-                                     self.cur_token[slot]))
-            self.host_syncs += 1
-            entry.length = int(ln)
-            entry.cur_token = int(ct)
-            entry.fp_pages = self._slot_fp.pop(slot, None)
-            self.kv.arena.stash(entry)
-        else:
-            self._slot_fp.pop(slot, None)
-        self.active[slot] = None
-        self.backend.release(self.kv, slot)
-        self._bt = self.kv.tables()
+        with TraceAnnotation("engine.preempt", uid=req.uid):
+            if self.preempt_mode == "swap":
+                entry = self.backend.swap_out(self.kv, slot, self.caches)
+                entry.uid = req.uid
+                ln, ct = jax.device_get((self.lengths[slot],
+                                         self.cur_token[slot]))
+                self.host_syncs += 1
+                entry.length = int(ln)
+                entry.cur_token = int(ct)
+                entry.fp_pages = self._slot_fp.pop(slot, None)
+                self.kv.arena.stash(entry)
+            else:
+                self._slot_fp.pop(slot, None)
+            self.active[slot] = None
+            self.backend.release(self.kv, slot)
+            self._bt = self.kv.tables()
         req.preemptions += 1
         self.preemptions += 1
         self.preempt_log.append((self.step_count, req.uid))
@@ -498,17 +522,18 @@ class ContinuousBatchingEngine:
                 self._note_stall(req)
                 return False
             self.preempt(victim)
-        entry = self.kv.arena.pop(req.uid)
-        self._bt = self.kv.tables()
-        dests = self.backend.swap_dests(self.kv, slot, entry)
-        self.caches = self._restore_jit(
-            self.caches, entry.pages, dests, entry.dense,
-            jnp.asarray(slot, jnp.int32))
-        if entry.fp_pages is not None:
-            self._slot_fp[slot] = entry.fp_pages
-        self.active[slot] = req
-        self.lengths = self.lengths.at[slot].set(entry.length)
-        self.cur_token = self.cur_token.at[slot].set(entry.cur_token)
+        with TraceAnnotation("engine.restore", uid=req.uid):
+            entry = self.kv.arena.pop(req.uid)
+            self._bt = self.kv.tables()
+            dests = self.backend.swap_dests(self.kv, slot, entry)
+            self.caches = self._restore_jit(
+                self.caches, entry.pages, dests, entry.dense,
+                jnp.asarray(slot, jnp.int32))
+            if entry.fp_pages is not None:
+                self._slot_fp[slot] = entry.fp_pages
+            self.active[slot] = req
+            self.lengths = self.lengths.at[slot].set(entry.length)
+            self.cur_token = self.cur_token.at[slot].set(entry.cur_token)
         if self._stalled_uid == req.uid:
             self._stalled_uid = None
         return True
@@ -581,12 +606,14 @@ class ContinuousBatchingEngine:
         if resumed:
             tok = req.output[-1]
         else:
-            self._rng, sub = jax.random.split(self._rng)
-            eos_arr = serving_steps.as_eos_array(req.eos_id, 1)
-            first, _ = serving_steps.first_token(
-                sub, last_logits, eos_arr, temperature=self.temperature,
-                top_k=self.top_k)
-            tok = int(first[0])
+            with TraceAnnotation("engine.first_token", uid=req.uid):
+                self._rng, sub = jax.random.split(self._rng)
+                eos_arr = serving_steps.as_eos_array(req.eos_id, 1)
+                first, _ = serving_steps.first_token(
+                    sub, last_logits, eos_arr, temperature=self.temperature,
+                    top_k=self.top_k)
+                tok = int(first[0])
+            req.t_first = time.perf_counter()
             self.host_syncs += 1
             req.output.append(tok)
             req.first_token_step = self.step_count
@@ -595,6 +622,13 @@ class ContinuousBatchingEngine:
         self.cur_token = self.cur_token.at[slot].set(tok)
         if not resumed:
             self._maybe_finish(slot, tok)
+
+    def _leave_queue(self, req: Request) -> None:
+        """Take an admitted (or restored) request off the queue, stamping
+        its first admission."""
+        self.queue.remove(req)
+        if req.t_admit is None:
+            req.t_admit = time.perf_counter()
 
     def _admit(self) -> None:
         if self.prefill_mode == "padded":
@@ -631,20 +665,21 @@ class ContinuousBatchingEngine:
             if self.preempt_mode == "swap" and self.kv.arena.holds(req.uid):
                 if not self._restore(req, slot):
                     return
-                self.queue.remove(req)
+                self._leave_queue(req)
                 continue
             granted = self._grant_or_preempt(slot, req)
             if granted is None:
                 return
             n, _, _ = granted  # padded mode never prefix-caches
-            self.queue.remove(req)
+            self._leave_queue(req)
             seq = self._resume_seq(req)
             toks = np.zeros((1, self.max_len), np.int32)
             toks[0, :n] = seq[:n]
-            last_logits, self.caches = self._prefill(
-                self.params, jnp.asarray(toks), jnp.asarray(n, jnp.int32),
-                jnp.asarray(slot, jnp.int32), self.caches,
-                self._slot_tables(slot))
+            with TraceAnnotation("engine.prefill_chunk", uid=req.uid):
+                last_logits, self.caches = self._prefill(
+                    self.params, jnp.asarray(toks),
+                    jnp.asarray(n, jnp.int32), jnp.asarray(slot, jnp.int32),
+                    self.caches, self._slot_tables(slot))
             self._finish_admission(req, slot, n, last_logits)
 
     def _start_pending(self) -> None:
@@ -662,13 +697,13 @@ class ContinuousBatchingEngine:
             return
         if self.preempt_mode == "swap" and self.kv.arena.holds(req.uid):
             if self._restore(req, slot):
-                self.queue.remove(req)
+                self._leave_queue(req)
             return
         granted = self._grant_or_preempt(slot, req)
         if granted is None:
             return
         n, reuse, fp_pages = granted
-        self.queue.remove(req)
+        self._leave_queue(req)
         seq = self._resume_seq(req)
         caches = self.kv.init_cache(1, prefill_scratch=True)
         if self.backend.paged:
@@ -695,6 +730,16 @@ class ContinuousBatchingEngine:
         pend = self._pending
         if pend is None:
             return
+        with TraceAnnotation("engine.prefill_chunk", uid=pend.req.uid):
+            landed = self._run_chunk(pend)
+        if landed:
+            self._pending = None
+            self._finish_admission(pend.req, pend.slot, pend.n,
+                                   pend.last_logits)
+
+    def _run_chunk(self, pend: _PendingPrefill) -> bool:
+        """Dispatch ``pend``'s next chunk; after the last one, merge the
+        batch-1 cache into the live one.  True once the prompt has landed."""
         if self.backend.paged:
             # decode ticks between chunks produced fresh pool arrays
             pend.caches = kvc.adopt_pools(pend.caches, self.caches)
@@ -712,7 +757,7 @@ class ContinuousBatchingEngine:
         if self.backend.paged:
             self.caches = kvc.adopt_pools(self.caches, pend.caches)
         if pend.next_chunk < len(pend.plan):
-            return
+            return False
         if self.prefix_cache and self.backend.vq_codes:
             # capture the exact fp scratch per prompt page before it is
             # stripped — retirement hands these to the prefix index
@@ -729,9 +774,7 @@ class ContinuousBatchingEngine:
             fresh = kvc.strip_pool_leaves(fresh)
         self.caches = self._merge(self.caches, fresh,
                                   jnp.asarray(pend.slot, jnp.int32))
-        self._pending = None
-        self._finish_admission(pend.req, pend.slot, pend.n,
-                               pend.last_logits)
+        return True
 
     def _maybe_finish(self, slot: int, tok: int) -> bool:
         req = self.active[slot]
@@ -740,6 +783,7 @@ class ContinuousBatchingEngine:
         if (req.eos_id is not None and tok == req.eos_id) or \
                 len(req.output) >= req.max_new_tokens:
             req.done_step = self.step_count
+            req.t_done = time.perf_counter()
             self.finished.append(req)
             self.active[slot] = None
             if self.prefix_cache:
@@ -762,11 +806,24 @@ class ContinuousBatchingEngine:
         """One scheduler iteration: admit + one on-device decode chunk (up
         to ``decode_chunk`` tokens) for all active slots.  Returns the
         number of tokens emitted this iteration."""
-        self._admit()
-        n_active = sum(r is not None for r in self.active)
-        if n_active == 0:
+        with TraceAnnotation("engine.step"):
+            with TraceAnnotation("engine.admit"):
+                self._admit()
+            if all(r is None for r in self.active):
+                self.step_count += 1
+                return 0
+            with TraceAnnotation("engine.decode_dispatch"):
+                width, toks_d, valid_d = self._dispatch_decode()
+            with TraceAnnotation("engine.sync"):
+                toks_h, valid_h = jax.device_get((toks_d, valid_d))
+            self.host_syncs += 1
             self.step_count += 1
-            return 0
+            with TraceAnnotation("engine.emit"):
+                return self._emit(width, toks_h, valid_h)
+
+    def _dispatch_decode(self):
+        """Build the per-row inputs and dispatch one decode (or verify)
+        chunk; returns ``(width, tokens, valid)`` still on device."""
         remaining = jnp.asarray(
             [(r.max_new_tokens - len(r.output)) if r is not None else 0
              for r in self.active], jnp.int32)
@@ -806,9 +863,11 @@ class ContinuousBatchingEngine:
                                    temperature=self.temperature,
                                    top_k=self.top_k)
         self.cur_token = cur
-        toks_h, valid_h = jax.device_get((toks_d, valid_d))
-        self.host_syncs += 1
-        self.step_count += 1
+        return width, toks_d, valid_d
+
+    def _emit(self, width: int, toks_h, valid_h) -> int:
+        """Append the chunk's valid tokens to their requests and retire the
+        finished ones; returns the number of tokens emitted."""
         if self.spec_k:
             self.spec_rounds += 1
             self.spec_active_rows += int(valid_h[:, 0].sum())
@@ -854,13 +913,15 @@ class ContinuousBatchingEngine:
                 and all(r is None for r in self.active))
 
     def run_until_drained(self, max_steps: int = 10_000) -> Dict[str, Any]:
-        t0 = time.time()
+        t0 = time.perf_counter()
         decoded = 0
         while not self.idle and self.step_count < max_steps:
             decoded += self.step()
-        dt = max(time.time() - t0, 1e-9)
+        dt = max(time.perf_counter() - t0, 1e-9)
         ttfts = [r.first_token_step - r.submitted_step
                  for r in self.finished]
+        ttft_ms = [1e3 * (r.t_first - r.t_submit) for r in self.finished]
+        e2e_ms = [1e3 * (r.t_done - r.t_submit) for r in self.finished]
         return {
             "requests": len(self.finished),
             "tokens": sum(len(r.output) for r in self.finished),
@@ -871,6 +932,14 @@ class ContinuousBatchingEngine:
             "p50_ttft_steps": float(np.percentile(ttfts, 50)) if ttfts
             else 0.0,
             "p99_ttft_steps": float(np.percentile(ttfts, 99)) if ttfts
+            else 0.0,
+            "ttft_ms_p50": float(np.percentile(ttft_ms, 50)) if ttft_ms
+            else 0.0,
+            "ttft_ms_p90": float(np.percentile(ttft_ms, 90)) if ttft_ms
+            else 0.0,
+            "e2e_ms_p50": float(np.percentile(e2e_ms, 50)) if e2e_ms
+            else 0.0,
+            "e2e_ms_p90": float(np.percentile(e2e_ms, 90)) if e2e_ms
             else 0.0,
             "admission_stalls": self.admission_stalls,
             "preemptions": self.preemptions,

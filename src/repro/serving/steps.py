@@ -233,8 +233,9 @@ def decode_chunk(
         logits, caches = tlm.lm_decode_step(params, cur[:, None], caches,
                                             lengths, ctx=ctx,
                                             block_tables=block_tables)
-        nxt = sample_tokens(step_rng, logits[:, 0], temperature=temperature,
-                            top_k=top_k)
+        with jax.named_scope("sample"):
+            nxt = sample_tokens(step_rng, logits[:, 0],
+                                temperature=temperature, top_k=top_k)
         active = jnp.logical_and(~done, remaining > 0)
         nxt = jnp.where(active, nxt, cur)
         lengths = lengths + active.astype(lengths.dtype)
@@ -360,8 +361,9 @@ def verify_chunk(
     toks, valids = [], []
     reach = jnp.ones_like(done)
     for j in range(w):
-        t_j = sample_tokens(step_rngs[j], logits[:, j],
-                            temperature=temperature, top_k=top_k)
+        with jax.named_scope("sample"):
+            t_j = sample_tokens(step_rngs[j], logits[:, j],
+                                temperature=temperature, top_k=top_k)
         active = reach & ~done & (remaining > 0)
         nxt = jnp.where(active, t_j, cur)
         lengths = lengths + active.astype(lengths.dtype)
@@ -388,9 +390,10 @@ def first_token(rng: jax.Array, last_logits: jax.Array, eos_ids: jax.Array,
     scan step above — the historical "first token never checked against
     eos_id" bug is impossible by construction.
     """
-    cur = sample_tokens(rng, last_logits, temperature=temperature,
-                        top_k=top_k)
-    return cur, (eos_ids >= 0) & (cur == eos_ids)
+    with jax.named_scope("sample"):
+        cur = sample_tokens(rng, last_logits, temperature=temperature,
+                            top_k=top_k)
+        return cur, (eos_ids >= 0) & (cur == eos_ids)
 
 
 def as_eos_array(eos_id, batch: int) -> jax.Array:
