@@ -3,11 +3,12 @@
 This is the reusable home of what ``launch/dryrun.py`` used to do with
 private regexes: scan a compiled executable's HLO text for oversized
 collectives (the decode-step guard against involuntary rematerialization
-of a sharded table — the gather shows up as a table-sized all-gather)
-and check that donated buffers were actually aliased
-(``input_output_alias`` annotations on the module header).  Pure string
-parsing, no jax import — the CI lint lane can audit saved HLO dumps
-without an accelerator stack.
+of a sharded table — the gather shows up as a table-sized all-gather),
+check that donated buffers were actually aliased (``input_output_alias``
+annotations on the module header), and find ops that copy a whole
+stacked page pool or work on one layer's slice of it
+(:func:`pool_copy_findings`).  Pure string parsing, no jax import — the
+CI lint lane can audit saved HLO dumps without an accelerator stack.
 
 Findings reuse :class:`repro.analysis.rules.Finding`; ``path`` carries
 the caller's label (e.g. ``decode_chunk[fp]``) and ``line`` the HLO text
@@ -169,4 +170,86 @@ def audit_hlo(hlo: str, *, label: str,
                     f"donated parameter {p} has no input_output_alias "
                     f"entry — XLA is copying the buffer, not updating "
                     f"in place"))
+    return findings
+
+
+# ops that move a whole buffer: on a stacked page pool each one costs the
+# pool's full size in HBM traffic, where an in-place step needs none
+POOL_COPY_OPS = ("copy", "copy-start", "copy-done", "broadcast",
+                 "dynamic-update-slice")
+
+_INSTR_RE = re.compile(r"^\s*(?:ROOT\s+)?%?[\w.\-]+\s*=\s*(.*)$")
+
+
+def _instr_types_and_op(line: str) -> Optional[Tuple[List[str], str]]:
+    """``(["f32[2,17,8]", ...], opcode)`` of one HLO instruction line:
+    every array type of its result (a tuple result lists several) and its
+    opcode; None for lines that are not instructions."""
+    m = _INSTR_RE.match(line)
+    if not m:
+        return None
+    rest = m.group(1)
+    if rest.startswith("("):  # tuple result: up to the matching paren
+        depth = 0
+        for i, ch in enumerate(rest):
+            depth += {"(": 1, ")": -1}.get(ch, 0)
+            if depth == 0:
+                break
+        type_seg, rest = rest[:i + 1], rest[i + 1:]
+    else:
+        type_seg, _, rest = rest.partition(" ")
+    op = re.match(r"\s*([\w\-]+)\(", rest)
+    if not op:
+        return None
+    types = [f"{dt}[{dims}]" for dt, dims in _SHAPE_RE.findall(type_seg)]
+    return types, op.group(1)
+
+
+def _elements(dims: str) -> int:
+    n = 1
+    for d in dims.split(","):
+        if d:
+            n *= int(d)
+    return n
+
+
+def pool_copy_findings(hlo: str, *, label: str,
+                       pools: Sequence[str]) -> List[Finding]:
+    """Ops that copy a stacked page pool or touch one layer's slice of it.
+
+    ``pools`` are the stacked pools' HLO types, e.g. ``"f32[12,513,16,12,
+    64]"`` (layers first).  With the pools resident in the layer scan's
+    carry and donated, the compiled step reaches them only through the
+    token scatter and the page gather.  So any ``POOL_COPY_OPS`` op whose
+    result is a whole stacked pool, and any op at all whose result is one
+    layer's pool, is a finding (``hlo-pool-copy``): a per-layer slice, a
+    write-back, or a whole-pool copy.  Results are matched by element type
+    and count, so a pool reshaped first (pages of all layers merged, say)
+    is still found."""
+    stacked, layer = set(), set()
+    for t in pools:
+        dt, dims = _SHAPE_RE.fullmatch(t).groups()
+        reps = int(dims.split(",")[0])
+        stacked.add((dt, _elements(dims)))
+        layer.add((dt, _elements(dims) // reps))
+    findings: List[Finding] = []
+    for lineno, line in enumerate(hlo.splitlines(), 1):
+        parsed = _instr_types_and_op(line)
+        if parsed is None:
+            continue
+        types, op = parsed
+        sizes = [(t, (_SHAPE_RE.fullmatch(t).group(1),
+                      _elements(_SHAPE_RE.fullmatch(t).group(2))))
+                 for t in types]
+        hit = next((t for t, k in sizes if k in layer), None)
+        what = "works on one layer's slice of"
+        if hit is None and op in POOL_COPY_OPS:
+            hit = next((t for t, k in sizes if k in stacked), None)
+            what = "copies or rewrites the whole stacked"
+        if hit is not None:
+            findings.append(Finding(
+                label, lineno, "hlo-pool-copy",
+                f"{op} on {hit} {what} page pool: the step should reach "
+                f"the pool only through the token scatter and the page "
+                f"gather"))
     return findings

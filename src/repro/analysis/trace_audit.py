@@ -6,9 +6,12 @@ constructs a reduced serving engine, lowers the jitted ``decode_chunk``
 and ``prefill_chunk`` through ``CountingJit.lower``, and audits
 
 * the optimized HLO via :mod:`repro.analysis.hlo` — no embed/table-sized
-  all-gather in the decode step (the dryrun invariant, now shared), and
+  all-gather in the decode step (the dryrun invariant, now shared),
   ``input_output_alias`` entries present whenever the step was built
-  with donated cache buffers on a platform that aliases;
+  with donated cache buffers on a platform that aliases, and, for a
+  donated step whose backend keeps its page pools resident in the layer
+  scan, no op that copies a stacked pool or works on one layer's slice
+  of it (``hlo-pool-copy``);
 * kernel engagement via ``kernels.ops.KERNEL_INVOCATIONS`` deltas — with
   ``use_pallas=True`` the Pallas wrappers must have traced (a silent
   jnp fallback passes every parity test while shipping the slow path),
@@ -34,7 +37,15 @@ DEFAULT_MATRIX: Tuple[Tuple, ...] = (
     ("fp", False, True),
     ("fp", True, True),
     ("vq", True, True),
+    # (..., seq_sharded, donate): the pool audit needs donated steps, which
+    # the CPU's default would filter out
+    ("paged", False, False, True),
+    ("paged_vq", False, False, True),
 )
+
+# numpy dtype name -> HLO element type, for the stacked pools' HLO types
+_HLO_DTYPES = {"float32": "f32", "bfloat16": "bf16", "float16": "f16",
+               "uint8": "u8", "uint16": "u16", "int32": "s32"}
 
 _MODELS: Dict[Tuple[str, bool], tuple] = {}
 
@@ -82,8 +93,17 @@ class StepAudit:
         }
 
 
+def _pool_types(caches, keys) -> List[str]:
+    """HLO types (``f32[reps,N,ps,...]``) of a cache tree's leaves named in
+    ``keys`` — the stacked page pools a backend keeps resident."""
+    return sorted({
+        f"{_HLO_DTYPES[x.dtype.name]}[{','.join(map(str, x.shape))}]"
+        for stage in caches for sub in stage.values()
+        for k, x in sub.items() if k in keys})
+
+
 def _audit_compiled(lowered, *, label: str, embed_bytes: int,
-                    donated: bool) -> StepAudit:
+                    donated: bool, pools=()) -> StepAudit:
     compiled = lowered.compile()
     text = compiled.as_text()
     findings = hlo_lint.audit_hlo(text, label=label,
@@ -95,6 +115,9 @@ def _audit_compiled(lowered, *, label: str, embed_bytes: int,
             "step was built with donated cache argnums but the compiled "
             "module has no input_output_alias entries — XLA is copying "
             "the cache every step"))
+    if donated:  # an undonated step has to copy its input pools
+        findings += hlo_lint.pool_copy_findings(text, label=label,
+                                                pools=pools)
     return StepAudit(
         label=label,
         hlo_lines=text.count("\n") + 1,
@@ -189,9 +212,11 @@ def audit_serving_step(cache_mode: str = "fp", use_pallas: bool = False,
 
     leaf = jax.tree.leaves(params)[0]
     embed_bytes = cfg.vocab_size * cfg.d_model * leaf.dtype.itemsize
+    keys = eng.backend.resident_keys
     audits = [_audit_compiled(
         lowered_decode, label=f"decode_chunk[{tag}]", embed_bytes=embed_bytes,
-        donated=bool(eng._decode_chunk.donate_argnums))]
+        donated=bool(eng._decode_chunk.donate_argnums),
+        pools=_pool_types(caches, keys))]
 
     if eng.prefill_mode == "chunked":
         if eng.backend.paged:
@@ -216,7 +241,8 @@ def audit_serving_step(cache_mode: str = "fp", use_pallas: bool = False,
         audits.append(_audit_compiled(
             lowered_prefill, label=f"prefill_chunk[{tag}]",
             embed_bytes=embed_bytes,
-            donated=bool(eng._prefill_chunk.donate_argnums)))
+            donated=bool(eng._prefill_chunk.donate_argnums),
+            pools=_pool_types(caches_p, keys)))
 
     findings = [f for a in audits for f in a.findings]
     findings += engagement_findings(delta, use_pallas=use_pallas,
@@ -304,12 +330,15 @@ def audit_chunked_admission(cache_mode: str = "paged", *,
 def audit_matrix(matrix: Sequence[Tuple] = DEFAULT_MATRIX,
                  **kw) -> Tuple[List[Finding], List[dict]]:
     """Run :func:`audit_serving_step` over a (cache_mode, use_pallas[,
-    seq_sharded]) matrix; returns merged findings + one report per combo."""
+    seq_sharded[, donate]]) matrix; returns merged findings + one report
+    per combo."""
     findings: List[Finding] = []
     reports: List[dict] = []
     for cache_mode, use_pallas, *rest in matrix:
         seq_sharded = bool(rest[0]) if rest else False
-        f, r = audit_serving_step(cache_mode, use_pallas, seq_sharded, **kw)
+        row_kw = {"donate": rest[1]} if len(rest) > 1 else {}
+        f, r = audit_serving_step(cache_mode, use_pallas, seq_sharded,
+                                  **{**kw, **row_kw})
         findings.extend(f)
         reports.append(r)
     return findings, reports

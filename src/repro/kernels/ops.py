@@ -47,6 +47,12 @@ from repro.kernels.vq_decode_attn import fp_decode_attention, vq_decode_attentio
 # shape); the conformance harness snapshots it around engine runs.
 KERNEL_INVOCATIONS: collections.Counter = collections.Counter()
 
+# trace-time counter of model-step paths that are not kernels, read the same
+# way: "pool_in_place" counts layer-scan bodies traced with the paged pools
+# resident in the scan's carry (``models.transformer.run_stages``), so a
+# silent fall back to per-layer pool slices shows as zero hits.
+PATH_INVOCATIONS: collections.Counter = collections.Counter()
+
 
 def on_tpu() -> bool:
     return jax.default_backend() == "tpu"

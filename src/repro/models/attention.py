@@ -113,9 +113,13 @@ def attention_forward(
     cache: Optional[Dict] = None,
     block_tables=None,
     lengths: Optional[jax.Array] = None,
+    page_base: Optional[jax.Array] = None,
 ) -> Tuple[jax.Array, jax.Array, Optional[Dict]]:
     """Returns (y, aux, new_cache).  aux = dict(commit=.., navq=(per-dim
-    residual mean/var for K and V) or zeros)."""
+    residual mean/var for K and V) or zeros).  ``page_base`` (here and in
+    the decode/verify/chunk entry points) is where this layer's pages start
+    in the layer-merged pools the backend keeps resident
+    (``CacheBackend.resident_keys``)."""
     cfg = ctx.cfg
     t = x.shape[1]
     window = kind_window(kind, cfg)
@@ -157,7 +161,8 @@ def attention_forward(
         with jax.named_scope("kv_write"):
             new_cache = ctx.backend.prefill_write(
                 cache, k, v, ctx=ctx, kind=kind, vq_params=vq_params,
-                block_tables=block_tables, lengths=lengths)
+                block_tables=block_tables, lengths=lengths,
+                page_base=page_base)
     return out_proj(params, out), aux, new_cache
 
 
@@ -226,6 +231,7 @@ def attention_decode(
     kind: str,
     vq_params: Optional[Dict] = None,
     block_tables=None,
+    page_base: Optional[jax.Array] = None,
 ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
     """One decode step.  x: (B, 1, D); lengths: (B,) current sequence length
     (the new token's position).  Returns (y, new_cache)."""
@@ -234,7 +240,8 @@ def attention_decode(
     q, k_new, v_new = qkv(params, x, cfg, positions, kind_theta(kind, cfg))
     return ctx.backend.decode_attend(
         params, q, k_new, v_new, cache, lengths, ctx=ctx, kind=kind,
-        vq_params=vq_params, block_tables=block_tables)
+        vq_params=vq_params, block_tables=block_tables,
+        page_base=page_base)
 
 
 def attention_verify(
@@ -247,6 +254,7 @@ def attention_verify(
     kind: str,
     vq_params: Optional[Dict] = None,
     block_tables=None,
+    page_base: Optional[jax.Array] = None,
 ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
     """Speculative verify step: score W = k+1 positions in one forward.
 
@@ -264,7 +272,8 @@ def attention_verify(
     q, k_new, v_new = qkv(params, x, cfg, positions, kind_theta(kind, cfg))
     return ctx.backend.verify_attend(
         params, q, k_new, v_new, cache, starts, ctx=ctx, kind=kind,
-        vq_params=vq_params, block_tables=block_tables)
+        vq_params=vq_params, block_tables=block_tables,
+        page_base=page_base)
 
 
 def _masked_decode_attn(params, q, k_all, v_all, valid, cap) -> jax.Array:
@@ -339,6 +348,7 @@ def attention_chunk(
     vq_params: Optional[Dict] = None,
     block_tables=None,
     history_len: int = 0,
+    page_base: Optional[jax.Array] = None,
 ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
     """One chunked-prefill step: RoPE at the chunk's global positions, then
     the backend writes the chunk's K/V into the cache and attends causally
@@ -351,7 +361,7 @@ def attention_chunk(
     return ctx.backend.chunk_attend(
         params, q, k_new, v_new, cache, chunk_start, lengths, ctx=ctx,
         kind=kind, vq_params=vq_params, block_tables=block_tables,
-        history_len=history_len)
+        history_len=history_len, page_base=page_base)
 
 
 def _masked_chunk_attn(params, q, k_all, v_all, q_pos, k_pos, window,

@@ -12,6 +12,7 @@ from typing import Any, Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.experimental.layout import Layout, with_layout_constraint
 
 from repro.core import navq
 from repro.models import attention as attn
@@ -136,6 +137,7 @@ def block_forward(
     chunk_start: Optional[jax.Array] = None,
     history_len: int = 0,
     verify_starts: Optional[jax.Array] = None,
+    page_base: Optional[jax.Array] = None,
 ) -> Tuple[jax.Array, Dict[str, jax.Array], Dict, Optional[Dict]]:
     """``chunk_start`` (traced scalar) switches prefill into chunked mode:
     ``x`` is one fixed-width chunk at global offset ``chunk_start``,
@@ -149,7 +151,11 @@ def block_forward(
     one pass through ``ctx.backend.verify_attend``.  It takes precedence
     over the plain decode dispatch and is attention-only — recurrent and
     SSM layers advance irreversible state per token and cannot re-score a
-    drafted block, so they raise."""
+    drafted block, so they raise.
+
+    ``page_base`` (traced) is set when ``cache`` holds the backend's
+    resident leaves as layer-merged pools, and says where this layer's
+    pages start in them (see ``run_stages``)."""
     cfg = ctx.cfg
     if verify_starts is not None and kind not in ATTN_KINDS:
         raise ValueError(
@@ -169,22 +175,25 @@ def block_forward(
         if verify_starts is not None:
             y, new_cache = attn.attention_verify(
                 p["attn"], h, cache, verify_starts, ctx=ctx, kind=kind,
-                vq_params=p.get("vq"), block_tables=block_tables)
+                vq_params=p.get("vq"), block_tables=block_tables,
+                page_base=page_base)
         elif ctx.mode == "decode":
             y, new_cache = attn.attention_decode(
                 p["attn"], h, cache, lengths, ctx=ctx, kind=kind,
-                vq_params=p.get("vq"), block_tables=block_tables)
+                vq_params=p.get("vq"), block_tables=block_tables,
+                page_base=page_base)
         elif chunk_start is not None:
             y, new_cache = attn.attention_chunk(
                 p["attn"], h, cache, chunk_start, lengths, ctx=ctx,
                 kind=kind, vq_params=p.get("vq"),
-                block_tables=block_tables, history_len=history_len)
+                block_tables=block_tables, history_len=history_len,
+                page_base=page_base)
         else:
             y, a, new_cache = attn.attention_forward(
                 p["attn"], h, ctx=ctx, kind=kind, causal=causal,
                 vq_params=p.get("vq"), navq_stats=navq_stats or None,
                 rng=rng, cache=cache, block_tables=block_tables,
-                lengths=lengths)
+                lengths=lengths, page_base=page_base)
             aux["commit"] = a["commit"]
             if navq_stats:
                 new_navq = {
@@ -332,6 +341,20 @@ def _embed_inputs(params, batch: Dict, cfg) -> jax.Array:
     return x
 
 
+def _split_resident(cache_stage: Dict, keys) -> Tuple[Dict, Dict]:
+    """Split one stage's cache ``{sub: {leaf: array}}`` into the leaves
+    named in ``keys`` and the rest.  Subs with no leaf on one side are left
+    out of it."""
+    resident, scanned = {}, {}
+    for name, sub in cache_stage.items():
+        r = {k: v for k, v in sub.items() if k in keys}
+        if r:
+            resident[name] = r
+        if len(r) < len(sub):
+            scanned[name] = {k: v for k, v in sub.items() if k not in keys}
+    return resident, scanned
+
+
 def run_stages(
     params_stages: List[Dict],
     x: jax.Array,
@@ -348,44 +371,81 @@ def run_stages(
     history_len: int = 0,
     verify_starts: Optional[jax.Array] = None,
 ) -> Tuple[jax.Array, Dict[str, jax.Array], List[Dict], Optional[List[Dict]]]:
+    """Run every stage as one ``lax.scan`` over its stacked layers.
+
+    Cache leaves the backend names in ``resident_keys`` (the paged pools,
+    stacked ``(reps, N, ps, ...)``) ride the scan's carry with every
+    layer's pages merged into one ``(reps * N, ps, ...)`` pool: each layer
+    writes its new tokens into its own pages in place and gathers them from
+    there (``page_base = layer * N``), so no step slices out, writes back
+    or copies a whole layer's pool.  Every other leaf is the scan's
+    per-layer xs/ys.  The returned cache tree has the input's leaves,
+    shapes and dtypes either way."""
     commit = jnp.zeros((), jnp.float32)
     moe_aux = jnp.zeros((), jnp.float32)
     new_navq_all, new_caches_all = [], []
     base_rng = rng if rng is not None else jax.random.PRNGKey(0)
+    keys = ctx.backend.resident_keys if caches is not None else frozenset()
 
     for si, (kinds, reps) in enumerate(stages(cfg)):
         p_stage = params_stages[si]
         navq_stage = (navq_state[si] if navq_state else {})
         cache_stage = (caches[si] if caches is not None else {})
+        stacked, cache_stage = _split_resident(cache_stage, keys)
+        pools = jax.tree.map(lambda a: a.reshape((-1,) + a.shape[2:]),
+                             stacked)
+        # pages per layer, per sub (window and global page groups differ)
+        n_pages = {name: next(iter(sub.values())).shape[1]
+                   for name, sub in stacked.items()}
         rngs = jax.random.split(jax.random.fold_in(base_rng, si), reps)
 
         def body(carry, xs):
-            xx, cm, ma = carry
-            p_l, rng_l, navq_l, cache_l = xs
-            navq_outs, cache_outs = {}, {}
+            xx, cm, ma, pools = carry
+            p_l, rng_l, navq_l, cache_l, layer = xs
+            navq_outs, cache_outs, pool_outs = {}, {}, {}
+            if pools:
+                from repro.kernels.ops import PATH_INVOCATIONS
+
+                PATH_INVOCATIONS["pool_in_place"] += 1
             for j, kind in enumerate(kinds):
-                nst = navq_l.get(f"sub{j}") or None
-                cst = cache_l.get(f"sub{j}") if cache_l else None
+                name = f"sub{j}"
+                nst = navq_l.get(name) or None
+                cst = ({**cache_l.get(name, {}), **pools.get(name, {})}
+                       if caches is not None else None)
                 xx, aux, n_new, c_new = block_forward(
-                    p_l[f"sub{j}"], xx, ctx=ctx, kind=kind, causal=causal,
+                    p_l[name], xx, ctx=ctx, kind=kind, causal=causal,
                     rng=jax.random.fold_in(rng_l, j), navq_stats=nst,
                     cache=cst, lengths=lengths, block_tables=block_tables,
                     chunk_start=chunk_start, history_len=history_len,
-                    verify_starts=verify_starts)
+                    verify_starts=verify_starts,
+                    page_base=(layer * n_pages[name] if name in pools
+                               else None))
                 cm = cm + aux["commit"]
                 ma = ma + aux["moe_aux"]
                 if n_new:
-                    navq_outs[f"sub{j}"] = n_new
+                    navq_outs[name] = n_new
                 if c_new is not None:
-                    cache_outs[f"sub{j}"] = c_new
-            return (xx, cm, ma), (navq_outs, cache_outs)
+                    pool_l, rest = _split_resident({name: c_new}, keys)
+                    pool_outs.update(pool_l)
+                    cache_outs.update(rest)
+            return (xx, cm, ma, pool_outs), (navq_outs, cache_outs)
 
         scan_body = jax.checkpoint(body) if ctx.remat else body
-        (x, commit, moe_aux), (navq_out, cache_out) = jax.lax.scan(
-            scan_body, (x, commit, moe_aux),
-            (p_stage, rngs, navq_stage, cache_stage))
+        (x, commit, moe_aux, pools), (navq_out, cache_out) = jax.lax.scan(
+            scan_body, (x, commit, moe_aux, pools),
+            (p_stage, rngs, navq_stage, cache_stage, jnp.arange(reps)))
+        # back to (reps, N, ...) in the default dim order, which the pools
+        # carried by an outer loop (the decode chunk's step scan) keep:
+        # left free, the CPU compiler reorders a pool with a size-1 minor
+        # dim and copies it in and out of the layer scan every step
+        pools = jax.tree.map(
+            lambda a, s: with_layout_constraint(
+                a.reshape(s.shape), Layout(tuple(range(s.ndim)))),
+            pools, stacked)
         new_navq_all.append(navq_out)
-        new_caches_all.append(cache_out)
+        new_caches_all.append(
+            {name: {**cache_out.get(name, {}), **pools.get(name, {})}
+             for name in sorted(cache_out.keys() | pools.keys())})
 
     aux = {"commit": commit, "moe_aux": moe_aux}
     return x, aux, new_navq_all, (new_caches_all if caches is not None else None)

@@ -21,6 +21,11 @@ launcher).  This module is now the single owner of that dispatch: a
                          style); filtered to () on platforms where XLA
                          cannot alias (CPU) so donation stays a no-op there.
 
+The paged layouts name their page pools in ``resident_keys``: the layer
+scan then carries each pool whole, every layer's pages merged into one
+array, and the traced methods write and gather one layer's pages of it in
+place through ``page_base`` (``transformer.run_stages``).
+
 Everything outside this file talks to ``ctx.backend`` (resolved from
 ``StepCtx.cache_mode``); a tokenize-based grep test forbids ``cache_mode``
 string dispatch anywhere else, so adding a cache layout is one new class
@@ -48,6 +53,7 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.experimental.layout import Layout, with_layout_constraint
 from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
@@ -318,32 +324,53 @@ def _table_for(block_tables, kind: str, cfg) -> jax.Array:
     return block_tables  # single pre-selected table
 
 
+def _paged(pages: jax.Array, page_base) -> jax.Array:
+    """Page ids into a pool: as they are for one layer's pool, or shifted
+    to ``page_base`` (traced), where the layer's pages start in the
+    layer-merged pool ``transformer.run_stages`` keeps resident."""
+    return pages if page_base is None else pages + page_base
+
+
 @jax.named_scope("kv_write")
 def _pool_write(kp: jax.Array, vp: jax.Array, dest: jax.Array,
-                offs: jax.Array, k: jax.Array, v: jax.Array):
+                offs: jax.Array, k: jax.Array, v: jax.Array,
+                page_base=None):
     """Token-granular write into a K and a V page pool (N, ps, ...): token
     ``i`` of ``k``/``v`` (shaped ``dest.shape + (...)``) lands on page
-    ``dest[i]`` at offset ``offs[i]``."""
+    ``dest[i]`` at offset ``offs[i]`` (one scatter, in place into the
+    merged pool when ``page_base`` is given)."""
     n = dest.ndim
-    idx = (dest.reshape(-1), offs.reshape(-1))
+    idx = (_paged(dest.reshape(-1), page_base), offs.reshape(-1))
     return (kp.at[idx].set(k.reshape((-1,) + k.shape[n:]).astype(kp.dtype)),
             vp.at[idx].set(v.reshape((-1,) + v.shape[n:]).astype(vp.dtype)))
 
 
 @jax.named_scope("page_gather")
-def _pool_view(kp: jax.Array, vp: jax.Array, table: jax.Array):
+def _pool_view(kp: jax.Array, vp: jax.Array, table: jax.Array,
+               page_base=None):
     """Gather each row's pages through ``table`` (B, n) into contiguous
-    (B, n * ps, ...) K and V views."""
+    (B, n * ps, ...) K and V views, one gather each.  From the merged pool
+    (``page_base`` given) gathered fp pages (B, n, ps, Hkv, hd) are held
+    to the pool's own dim order: left free, the TPU compiler hands the
+    attention kernels their (B, Hkv, S, hd) view by relaying out the whole
+    merged pool, per layer."""
     b, n = table.shape
-    ps = kp.shape[1]
-    return (kp[table].reshape((b, n * ps) + kp.shape[2:]),
-            vp[table].reshape((b, n * ps) + vp.shape[2:]))
+    shape = (b, n * kp.shape[1]) + kp.shape[2:]
+    out = []
+    for pool in (kp, vp):
+        view = pool[_paged(table, page_base)]
+        if page_base is not None and view.ndim == 5:
+            view = with_layout_constraint(view, Layout(tuple(range(5))))
+        out.append(view.reshape(shape))
+    return tuple(out)
 
 
 def _scatter_pages(pool: jax.Array, vals: jax.Array, table: jax.Array,
-                   lengths: Optional[jax.Array]) -> jax.Array:
+                   lengths: Optional[jax.Array],
+                   page_base=None) -> jax.Array:
     """Write ``vals`` (B, T, ...) into ``pool`` (N, ps, ...) through a
-    block table whose span may be a ring (capped window tables).
+    block table whose span may be a ring (capped window tables); pages are
+    shifted to ``page_base`` as in ``_pool_write``.
 
     Fast path (prompt buffer fits the ring, the only case for full-span
     global tables): page ``i`` lands on table entry ``i`` wholesale; pages
@@ -370,7 +397,8 @@ def _scatter_pages(pool: jax.Array, vals: jax.Array, table: jax.Array,
         gathered = jnp.take_along_axis(vals, src, axis=1)  # (B, s, ...)
         dest = jnp.where(real, table[:, np.arange(s) // ps], 0)
         offs = jnp.broadcast_to(np.arange(s) % ps, (b, s))
-        return pool.at[dest.reshape(-1), offs.reshape(-1)].set(
+        return pool.at[_paged(dest.reshape(-1), page_base),
+                       offs.reshape(-1)].set(
             gathered.reshape((b * s,) + gathered.shape[2:]).astype(
                 pool.dtype))
     pad = n_pages * ps - t
@@ -381,7 +409,8 @@ def _scatter_pages(pool: jax.Array, vals: jax.Array, table: jax.Array,
     if lengths is not None:
         real = (np.arange(n_pages) * ps)[None, :] < lengths[:, None]
         dest = jnp.where(real, dest, 0)
-    return pool.at[dest.reshape(-1)].set(vals.astype(pool.dtype))
+    return pool.at[_paged(dest.reshape(-1), page_base)].set(
+        vals.astype(pool.dtype))
 
 
 # ---------------------------------------------------------------------------
@@ -396,6 +425,14 @@ class CacheBackend:
     paged = False      # block-table page pools (vs contiguous slabs)
     vq_codes = False   # global layers store VQ codes (Appendix G)
     sharded = False    # decode runs the seq-sharded shard_map path
+    # cache leaves the layer scan keeps resident in its carry, every
+    # layer's pages merged into one (reps * N, ps, ...) pool
+    # (``transformer.run_stages``): the per-layer methods below then get
+    # ``page_base``, the traced index of the layer's first page, and write
+    # and gather that layer's pages of the merged pool in place.  Every
+    # other leaf is sliced per layer, as the scan's xs/ys
+    # (``page_base=None``).
+    resident_keys: frozenset = frozenset()
 
     # -- layer level (jit-traced) -------------------------------------------
     def init_cache(self, cfg, kind: str, batch: int, max_len: int, dtype, *,
@@ -404,18 +441,18 @@ class CacheBackend:
         raise NotImplementedError
 
     def prefill_write(self, cache, k, v, *, ctx, kind: str, vq_params=None,
-                      block_tables=None, lengths=None) -> Dict:
+                      block_tables=None, lengths=None, page_base=None) -> Dict:
         raise NotImplementedError
 
     def decode_attend(self, params, q, k_new, v_new, cache, lengths, *, ctx,
-                      kind: str, vq_params=None,
-                      block_tables=None) -> Tuple[jax.Array, Dict]:
+                      kind: str, vq_params=None, block_tables=None,
+                      page_base=None) -> Tuple[jax.Array, Dict]:
         raise NotImplementedError
 
     def chunk_attend(self, params, q, k_new, v_new, cache, chunk_start,
                      lengths, *, ctx, kind: str, vq_params=None,
-                     block_tables=None,
-                     history_len: int = 0) -> Tuple[jax.Array, Dict]:
+                     block_tables=None, history_len: int = 0,
+                     page_base=None) -> Tuple[jax.Array, Dict]:
         """One chunked-prefill step: write the chunk's K/V (positions
         ``chunk_start .. chunk_start + W - 1``, length-masked where the
         layout needs it) and attend causally over everything cached so far
@@ -427,8 +464,8 @@ class CacheBackend:
             f"backend {self.name!r} does not support chunked prefill")
 
     def verify_attend(self, params, q, k_new, v_new, cache, starts, *, ctx,
-                      kind: str, vq_params=None,
-                      block_tables=None) -> Tuple[jax.Array, Dict]:
+                      kind: str, vq_params=None, block_tables=None,
+                      page_base=None) -> Tuple[jax.Array, Dict]:
         """Speculative verify: W = k+1 tokens per row at per-row positions
         ``starts[b] .. starts[b] + W - 1`` in one call.  Returns
         (y (B, W, ...), new_cache) with all W keys/values written — exactly
@@ -446,7 +483,7 @@ class CacheBackend:
             y, cache = self.decode_attend(
                 params, q[:, j:j + 1], k_new[:, j:j + 1], v_new[:, j:j + 1],
                 cache, starts + j, ctx=ctx, kind=kind, vq_params=vq_params,
-                block_tables=block_tables)
+                block_tables=block_tables, page_base=page_base)
             ys.append(y)
         return jnp.concatenate(ys, axis=1), cache
 
@@ -579,11 +616,11 @@ class FPSlabBackend(CacheBackend):
                 "v": jnp.zeros((batch, s, hkv, hd), dtype)}
 
     def prefill_write(self, cache, k, v, *, ctx, kind, vq_params=None,
-                      block_tables=None, lengths=None):
+                      block_tables=None, lengths=None, page_base=None):
         return _slab_prefill_fp(cache, k, v, lengths)
 
     def decode_attend(self, params, q, k_new, v_new, cache, lengths, *, ctx,
-                      kind, vq_params=None, block_tables=None):
+                      kind, vq_params=None, block_tables=None, page_base=None):
         cfg = ctx.cfg
         cap = cfg.attn_logit_softcap
         window = attn.kind_window(kind, cfg)
@@ -602,7 +639,7 @@ class FPSlabBackend(CacheBackend):
 
     def chunk_attend(self, params, q, k_new, v_new, cache, chunk_start,
                      lengths, *, ctx, kind, vq_params=None,
-                     block_tables=None, history_len=0):
+                     block_tables=None, history_len=0, page_base=None):
         cfg = ctx.cfg
         cap = cfg.attn_logit_softcap
         window = attn.kind_window(kind, cfg)
@@ -621,7 +658,7 @@ class FPSlabBackend(CacheBackend):
         return y, new
 
     def verify_attend(self, params, q, k_new, v_new, cache, starts, *, ctx,
-                      kind, vq_params=None, block_tables=None):
+                      kind, vq_params=None, block_tables=None, page_base=None):
         """Global layers: write all W verify tokens per-row (out-of-range
         positions dropped — a budget-exhausted row's tail can overhang the
         slab, and the unrolled path's clamping ``_write_at`` would shift
@@ -669,7 +706,7 @@ class VQSlabBackend(CacheBackend):
         return cache
 
     def prefill_write(self, cache, k, v, *, ctx, kind, vq_params=None,
-                      block_tables=None, lengths=None):
+                      block_tables=None, lengths=None, page_base=None):
         if "k_codes" not in cache:  # windowed fp ring
             return _slab_prefill_fp(cache, k, v, lengths)
         kc, vc, _ = _encode_pair(k, v, ctx.cfg, vq_params)
@@ -680,7 +717,7 @@ class VQSlabBackend(CacheBackend):
         return {"k_codes": ck, "v_codes": cv}
 
     def decode_attend(self, params, q, k_new, v_new, cache, lengths, *, ctx,
-                      kind, vq_params=None, block_tables=None):
+                      kind, vq_params=None, block_tables=None, page_base=None):
         cfg = ctx.cfg
         cap = cfg.attn_logit_softcap
         window = attn.kind_window(kind, cfg)
@@ -711,7 +748,7 @@ class VQSlabBackend(CacheBackend):
 
     def chunk_attend(self, params, q, k_new, v_new, cache, chunk_start,
                      lengths, *, ctx, kind, vq_params=None,
-                     block_tables=None, history_len=0):
+                     block_tables=None, history_len=0, page_base=None):
         cfg = ctx.cfg
         cap = cfg.attn_logit_softcap
         window = attn.kind_window(kind, cfg)
@@ -736,7 +773,7 @@ class VQSlabBackend(CacheBackend):
         return y, new
 
     def verify_attend(self, params, q, k_new, v_new, cache, starts, *, ctx,
-                      kind, vq_params=None, block_tables=None):
+                      kind, vq_params=None, block_tables=None, page_base=None):
         """Global coded layers: encode all W tokens at once (per-position
         encoding is order-independent), scatter the codes per-row with
         out-of-range drops, then attend over the dequantized slab — the
@@ -776,6 +813,7 @@ class PagedBackend(CacheBackend):
 
     name = "paged"
     paged = True
+    resident_keys = kvc.PAGED_LEAF_KEYS
 
     def _group_num_pages(self, num_pages, kind, cfg) -> int:
         if isinstance(num_pages, dict):
@@ -802,7 +840,7 @@ class PagedBackend(CacheBackend):
                 "v_pages": jnp.zeros((n, page_size, hkv, hd), dtype)}
 
     def prefill_write(self, cache, k, v, *, ctx, kind, vq_params=None,
-                      block_tables=None, lengths=None):
+                      block_tables=None, lengths=None, page_base=None):
         """Prompt K/V (or codes) straight into the page pools — no
         (B, max_len) slab is ever materialized or copied."""
         cfg = ctx.cfg
@@ -811,17 +849,19 @@ class PagedBackend(CacheBackend):
             kc, vc, _ = _encode_pair(k, v, cfg, vq_params)
             return {
                 "k_code_pages": _scatter_pages(cache["k_code_pages"], kc,
-                                               table, lengths),
+                                               table, lengths, page_base),
                 "v_code_pages": _scatter_pages(cache["v_code_pages"], vc,
-                                               table, lengths),
+                                               table, lengths, page_base),
             }
         return {
-            "k_pages": _scatter_pages(cache["k_pages"], k, table, lengths),
-            "v_pages": _scatter_pages(cache["v_pages"], v, table, lengths),
+            "k_pages": _scatter_pages(cache["k_pages"], k, table, lengths,
+                                      page_base),
+            "v_pages": _scatter_pages(cache["v_pages"], v, table, lengths,
+                                      page_base),
         }
 
     def decode_attend(self, params, q, k_new, v_new, cache, lengths, *, ctx,
-                      kind, vq_params=None, block_tables=None):
+                      kind, vq_params=None, block_tables=None, page_base=None):
         """Scatter-write the token's page slot (ring over the table span),
         gather the request's pages through the block table, then run the
         same dense masked decode attention as every other layout."""
@@ -840,11 +880,12 @@ class PagedBackend(CacheBackend):
         offs = jnp.mod(flat, ps)
         if vq_pool:
             kc, vc, _ = _encode_pair(k_new, v_new, cfg, vq_params)
-            kp, vp = _pool_write(kp, vp, page_ids, offs, kc[:, 0], vc[:, 0])
+            kp, vp = _pool_write(kp, vp, page_ids, offs, kc[:, 0], vc[:, 0],
+                                 page_base)
             new_cache = {"k_code_pages": kp, "v_code_pages": vp}
             # gather code pages into one contiguous (B, s, G) tile — the
             # kernels never see a block table, only block-aligned tiles
-            codes_k, codes_v = _pool_view(kp, vp, table)
+            codes_k, codes_v = _pool_view(kp, vp, table, page_base)
             if ctx.use_pallas and not window and _coded_kernel_ok(cfg):
                 y = attn._pallas_coded_decode_attn(params, q, codes_k,
                                                    codes_v, vq_params,
@@ -854,8 +895,8 @@ class PagedBackend(CacheBackend):
             v_all = _decode_codes(codes_v, cfg, vq_params, "v")
         else:
             kp, vp = _pool_write(kp, vp, page_ids, offs, k_new[:, 0],
-                                 v_new[:, 0])
-            k_all, v_all = _pool_view(kp, vp, table)
+                                 v_new[:, 0], page_base)
+            k_all, v_all = _pool_view(kp, vp, table, page_base)
             new_cache = {"k_pages": kp, "v_pages": vp}
         if ctx.use_pallas:
             # the gathered view is a ring over the table span; the kernel's
@@ -872,7 +913,7 @@ class PagedBackend(CacheBackend):
 
     def chunk_attend(self, params, q, k_new, v_new, cache, chunk_start,
                      lengths, *, ctx, kind, vq_params=None,
-                     block_tables=None, history_len=0):
+                     block_tables=None, history_len=0, page_base=None):
         """Token-granular chunk scatter through the block table (page-wise
         writes would need chunk/page alignment), then the same masked chunk
         attention as the slab layouts over the table-gathered view."""
@@ -889,7 +930,9 @@ class PagedBackend(CacheBackend):
         q_pos = chunk_start + jnp.arange(w)
 
         if window:  # fp page ring (windowed layers keep fp pages under vq)
-            ring_k, ring_v = _pool_view(kp, vp, table)
+            # read the ring before the write below, so the write can update
+            # the pool in place
+            ring_k, ring_v = _pool_view(kp, vp, table, page_base)
             k_pos = _ring_k_pos(s, chunk_start, w)
             k_all = jnp.concatenate([ring_k.astype(k_new.dtype), k_new], 1)
             v_all = jnp.concatenate([ring_v.astype(v_new.dtype), v_new], 1)
@@ -907,7 +950,7 @@ class PagedBackend(CacheBackend):
             gv = jnp.take_along_axis(v_new, idx, axis=1)
             dest = jnp.where(take, table[:, np.arange(s) // ps], 0)
             offs = jnp.broadcast_to(np.arange(s) % ps, (b, s))
-            kp, vp = _pool_write(kp, vp, dest, offs, gk, gv)
+            kp, vp = _pool_write(kp, vp, dest, offs, gk, gv, page_base)
             return y, {"k_pages": kp, "v_pages": vp}
 
         # global table: scatter the chunk token-granular (positions past the
@@ -918,7 +961,7 @@ class PagedBackend(CacheBackend):
         if vq_pool:
             _require_scratch(cache, self.name)
             kc, vc, _ = _encode_pair(k_new, v_new, cfg, vq_params)
-            kp, vp = _pool_write(kp, vp, dest, offs, kc, vc)
+            kp, vp = _pool_write(kp, vp, dest, offs, kc, vc, page_base)
             k_view = _chunk_slab_write(cache["k_fp"], k_new, chunk_start)
             v_view = _chunk_slab_write(cache["v_fp"], v_new, chunk_start)
             hv = _view_len(k_view.shape[1], history_len)
@@ -926,20 +969,20 @@ class PagedBackend(CacheBackend):
                                  chunk_start, hv, cap, ctx)
             return y, {"k_code_pages": kp, "v_code_pages": vp,
                        "k_fp": k_view, "v_fp": v_view}
-        kp, vp = _pool_write(kp, vp, dest, offs, k_new, v_new)
+        kp, vp = _pool_write(kp, vp, dest, offs, k_new, v_new, page_base)
         # gather only the first ceil(hv/ps) pages per row — the view length
         # ladder keeps both the gather (a block-aligned contiguous tile the
         # kernel can consume) and the score matrix prompt-sized
         hv = _view_len(s, history_len)
         n_view = -(-hv // ps)
         sv = n_view * ps
-        k_all, v_all = _pool_view(kp, vp, table[:, :n_view])
+        k_all, v_all = _pool_view(kp, vp, table[:, :n_view], page_base)
         y = _view_chunk_attn(params, q, k_all, v_all, chunk_start, sv, cap,
                              ctx)
         return y, {"k_pages": kp, "v_pages": vp}
 
     def verify_attend(self, params, q, k_new, v_new, cache, starts, *, ctx,
-                      kind, vq_params=None, block_tables=None):
+                      kind, vq_params=None, block_tables=None, page_base=None):
         """Global tables: token-granular per-row scatter of all W verify
         positions through the block table (out-of-span positions — a
         budget-exhausted row's overhang — route to the scratch page instead
@@ -951,7 +994,8 @@ class PagedBackend(CacheBackend):
         if attn.kind_window(kind, cfg):
             return CacheBackend.verify_attend(
                 self, params, q, k_new, v_new, cache, starts, ctx=ctx,
-                kind=kind, vq_params=vq_params, block_tables=block_tables)
+                kind=kind, vq_params=vq_params, block_tables=block_tables,
+                page_base=page_base)
         table = _table_for(block_tables, kind, cfg)
         vq_pool = "k_code_pages" in cache
         kp = cache["k_code_pages" if vq_pool else "k_pages"]
@@ -966,9 +1010,9 @@ class PagedBackend(CacheBackend):
         offs = jnp.mod(pos, ps)
         if vq_pool:
             kc, vc, _ = _encode_pair(k_new, v_new, cfg, vq_params)
-            kp, vp = _pool_write(kp, vp, dest, offs, kc, vc)
+            kp, vp = _pool_write(kp, vp, dest, offs, kc, vc, page_base)
             new_cache = {"k_code_pages": kp, "v_code_pages": vp}
-            codes_k, codes_v = _pool_view(kp, vp, table)
+            codes_k, codes_v = _pool_view(kp, vp, table, page_base)
             if ctx.use_pallas and _coded_kernel_ok(cfg):
                 ys = [attn._pallas_coded_decode_attn(
                           params, q[:, j:j + 1], codes_k, codes_v,
@@ -977,9 +1021,9 @@ class PagedBackend(CacheBackend):
             k_all = _decode_codes(codes_k, cfg, vq_params, "k")
             v_all = _decode_codes(codes_v, cfg, vq_params, "v")
         else:
-            kp, vp = _pool_write(kp, vp, dest, offs, k_new, v_new)
+            kp, vp = _pool_write(kp, vp, dest, offs, k_new, v_new, page_base)
             new_cache = {"k_pages": kp, "v_pages": vp}
-            k_all, v_all = _pool_view(kp, vp, table)
+            k_all, v_all = _pool_view(kp, vp, table, page_base)
         if ctx.use_pallas:
             y = _unrolled_pallas_verify(params, q, k_all, v_all, starts, 0,
                                         cap)
@@ -1061,14 +1105,14 @@ class ShardedBackend(CacheBackend):
                                      prefill_scratch=prefill_scratch)
 
     def prefill_write(self, cache, k, v, *, ctx, kind, vq_params=None,
-                      block_tables=None, lengths=None):
+                      block_tables=None, lengths=None, page_base=None):
         return self.inner.prefill_write(cache, k, v, ctx=ctx, kind=kind,
                                         vq_params=vq_params,
                                         block_tables=block_tables,
                                         lengths=lengths)
 
     def decode_attend(self, params, q, k_new, v_new, cache, lengths, *, ctx,
-                      kind, vq_params=None, block_tables=None):
+                      kind, vq_params=None, block_tables=None, page_base=None):
         cfg = ctx.cfg
         window = attn.kind_window(kind, cfg)
         if window:  # ring cache / page ring, replicated over the seq axis
@@ -1085,7 +1129,7 @@ class ShardedBackend(CacheBackend):
 
     def chunk_attend(self, params, q, k_new, v_new, cache, chunk_start,
                      lengths, *, ctx, kind, vq_params=None,
-                     block_tables=None, history_len=0):
+                     block_tables=None, history_len=0, page_base=None):
         cfg = ctx.cfg
         window = attn.kind_window(kind, cfg)
         if window:  # replicated ring / page ring: the inner layout's path

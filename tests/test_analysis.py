@@ -274,6 +274,42 @@ def test_audit_hlo_big_allgather_and_missing_alias():
     assert [f.rule for f in big_ar] == ["hlo-big-collective"]
 
 
+POOL_HLO = """\
+HloModule jit_decode
+%fused (param_0.1: f32[2,17,8,4,32]) -> f32[17,8,4,32] {
+  %param_0.1 = f32[2,17,8,4,32]{4,3,2,1,0} parameter(0)
+  ROOT %ds = f32[17,8,4,32]{3,2,1,0} dynamic-slice(f32[2,17,8,4,32]{4,3,2,1,0} \
+%param_0.1, s32[] %i), dynamic_slice_sizes={1,17,8,4,32}
+}
+ENTRY %e (p0: f32[2,17,8,4,32]) -> f32[2,17,8,4,32] {
+  %p0 = f32[2,17,8,4,32]{4,3,2,1,0} parameter(0)
+  %sc = f32[2,17,8,4,32]{4,3,2,1,0} scatter(%p0, %idx, %upd), to_apply=%set
+  %g = f32[16,1,1,8,4,32]{5,4,3,2,1,0} gather(%sc, %tab), slice_sizes={1,1,8,4,32}
+  %b = f32[2,17,8,4,32]{4,3,2,1,0} broadcast(f32[] %z), dimensions={}
+  %cs = (f32[2,17,8,4,32]{4,3,2,1,0}, f32[2,17,8,4,32]{4,3,2,1,0}, u32[]) \
+copy-start(%sc)
+  %w = (s32[], f32[2,17,8,4,32]{4,3,2,1,0}) while(%t), condition=%c, body=%bd
+  %u = f32[2,17,8,4,32]{4,3,2,1,0} dynamic-update-slice(%b, %x, %i, %i)
+  %m = f32[34,8,4,32]{3,2,1,0} copy(%u)
+  ROOT %cp = f32[2,17,8,4,32]{4,3,2,1,0} copy(%u)
+}
+"""
+
+
+def test_pool_copy_findings_flag_copies_and_layer_slices():
+    found = hlo.pool_copy_findings(POOL_HLO, label="decode",
+                                   pools=["f32[2,17,8,4,32]"])
+    assert {f.rule for f in found} == {"hlo-pool-copy"}
+    # the layer-shaped slice, then broadcast / copy-start / DUS / copy on
+    # the stacked pool, and a copy of it with all layers' pages merged;
+    # the parameter, scatter, gather and while are quiet
+    assert [f.line for f in found] == [4, 10, 11, 13, 14, 15]
+    assert "one layer's slice" in found[0].message
+    assert "whole stacked" in found[1].message
+    assert hlo.pool_copy_findings(POOL_HLO, label="decode",
+                                  pools=["f32[2,9,8,4,32]"]) == []
+
+
 # ---------------------------------------------------------------------------
 # Compiled-artifact trace audit (lowers the real jitted serving steps)
 # ---------------------------------------------------------------------------
@@ -290,6 +326,23 @@ def test_trace_audit_decode_and_prefill_clean_with_donation():
         assert step["donated"] and step["alias_entries"] > 0
     # jnp route: the Pallas wrappers must not have traced
     assert report["kernel_invocations"] == {}
+
+
+@pytest.mark.parametrize("mode", ["paged", "paged_vq"])
+def test_trace_audit_paged_pools_stay_in_place(mode):
+    """Donated decode and prefill chunks reach the stacked page pools only
+    through the token scatter and the page gather: no copy, broadcast or
+    dynamic-update-slice of a stacked pool, and no op on one layer's pool
+    (the per-layer xs/ys path compiles to all of these)."""
+    from repro.analysis.trace_audit import DEFAULT_MATRIX, audit_serving_step
+
+    assert (mode, False, False, True) in DEFAULT_MATRIX  # lint --trace
+    findings, report = audit_serving_step(mode, False, donate=True)
+    assert findings == [], "\n".join(str(f) for f in findings)
+    assert [s["label"] for s in report["steps"]] == [
+        f"decode_chunk[{mode}]", f"prefill_chunk[{mode}]"]
+    for step in report["steps"]:
+        assert step["donated"] and step["alias_entries"] > 0
 
 
 def test_trace_audit_pallas_engagement_and_big_allgather_guard():

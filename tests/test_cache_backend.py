@@ -18,16 +18,19 @@ import dataclasses
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 
 from repro.compat import make_mesh
 from repro.configs import get_config
 from repro.core.sequence_parallel import LOCAL, MeshContext
+from repro.kernels import ops as kops
 from repro.models import model_factory as mf
 from repro.models.context import StepCtx
 from repro.serving import autotune as serving_autotune
 from repro.serving import cache_backend as cbe
 from repro.serving.engine import ServingEngine
+from repro.serving import kv_cache as kvc
 from repro.serving.kv_cache import (
     PagedKVCache,
     page_group_spans,
@@ -467,6 +470,111 @@ def test_windowed_ring_prefill_ignores_prompt_padding():
     got = {tuple(r.prompt): r.output for r in eng.finished}
     for p, w in zip(prompts, want):
         assert got[tuple(p)] == w, (p, got[tuple(p)], w)
+
+
+# ---------------------------------------------------------------------------
+# Resident page pools: the layer scan carries the stacked pools and each
+# layer writes its tokens in place (transformer.run_stages)
+# ---------------------------------------------------------------------------
+
+
+def _pool_hits() -> int:
+    return kops.PATH_INVOCATIONS["pool_in_place"]
+
+
+def _prefill_then_decode(cfg, params, mode, prompts, prefill_mode):
+    """The cache tree after a prefill and after one 3-step decode chunk
+    (as host arrays), and the ``pool_in_place`` hits traced on the way."""
+    eng = ServingEngine(cfg, params, max_len=96, astra_mode="off",
+                        cache_mode=mode, page_size=8, decode_chunk=3,
+                        prefill_mode=prefill_mode)
+    b = len(prompts)
+    lens = np.array([len(p) for p in prompts], np.int32)
+    toks = np.zeros((b, int(lens.max())), np.int32)
+    for i, p in enumerate(prompts):
+        toks[i, :len(p)] = p
+    before = _pool_hits()
+    last, caches, tables = eng._run_prefill(toks, lens, 8)
+    prefilled = jax.device_get(caches)
+    out = eng._decode_chunk(
+        eng.params, jnp.argmax(last, -1).astype(jnp.int32), caches,
+        jnp.asarray(lens), jnp.full((b,), 8, jnp.int32),
+        jnp.full((b,), -1, jnp.int32), jnp.zeros((b,), bool),
+        jax.random.PRNGKey(0), tables, num_steps=3, temperature=0.0,
+        top_k=0)
+    return prefilled, jax.device_get(out[3]), _pool_hits() - before
+
+
+# (arch, mode): gpt2 global pools, gpt2 code pools, gemma2's windowed ring
+# group beside its global group; the gemma2 prompt overflows the 64-token
+# window, so the ring prefill takes the token-granular keep-latest path
+RESIDENT_CASES = [("gpt2-small", "paged"), ("gpt2-small", "paged_vq"),
+                  ("gemma2-27b", "paged")]
+
+
+@pytest.mark.parametrize("prefill_mode", ["chunked", "padded"])
+@pytest.mark.parametrize("arch,mode", RESIDENT_CASES)
+def test_resident_pools_match_layer_by_layer_writes(arch, mode,
+                                                    prefill_mode,
+                                                    monkeypatch):
+    """Pools written in place through the stacked arrays equal, bit for
+    bit, pools written layer by layer (each layer's pool sliced out, written
+    through the functional ``_pool_write`` / ``_scatter_pages`` and stacked
+    back) — scratch page 0 included."""
+    astra = mode == "paged_vq"
+    if arch == "gpt2-small":
+        cfg, params = small_lm(astra)
+        prompts = [[5, 9, 3], [7, 2, 8, 4, 1, 6, 3, 2, 9, 10, 4]]
+    else:
+        cfg = _no_astra(get_config(arch).reduced())
+        params = mf.init_params(jax.random.PRNGKey(0), cfg)
+        prompts = [[(7 * i) % 50 + 1 for i in range(cfg.window_size + 5)],
+                   [2, 8]]
+    got = _prefill_then_decode(cfg, params, mode, prompts, prefill_mode)
+    monkeypatch.setattr(cbe.PagedBackend, "resident_keys", frozenset())
+    want = _prefill_then_decode(cfg, params, mode, prompts, prefill_mode)
+    assert got[2] > 0 and want[2] == 0
+    for g, w in zip(got[:2], want[:2]):
+        assert (jax.tree_util.tree_structure(g)
+                == jax.tree_util.tree_structure(w))
+        for a, b in zip(jax.tree.leaves(g), jax.tree.leaves(w)):
+            assert a.shape == b.shape and a.dtype == b.dtype
+            np.testing.assert_array_equal(a, b)
+    pools = [x for stage in got[1] for sub in stage.values()
+             for k, x in sub.items() if k in kvc.PAGED_LEAF_KEYS]
+    assert any(np.any(x[:, 0]) for x in pools)  # scratch page 0 was hit
+    if arch == "gemma2-27b":  # a window ring group beside the global one
+        assert len({x.shape[1] for x in pools}) == 2
+
+
+@pytest.mark.parametrize("name", sorted(SPECS))
+def test_pool_in_place_counter_follows_the_backend(name):
+    """Single-host page pools take the resident path in every traced step;
+    slabs and the sequence-sharded wrapper (paged or not) never do."""
+    mode, _, sharded, _ = SPECS[name]
+    before = _pool_hits()
+    static_gen(name, PROMPTS[:2], 4)
+    resident = bool(cbe.get_backend(mode, seq_sharded=sharded).resident_keys)
+    assert resident == (name in ("paged", "paged_vq"))
+    assert (_pool_hits() > before) == resident
+
+
+@pytest.mark.parametrize("mode", ["paged", "paged_vq"])
+def test_pool_in_place_engages_in_verify(mode):
+    cfg, params = small_lm(mode == "paged_vq")
+    eng = ServingEngine(cfg, params, max_len=64, astra_mode="off",
+                        cache_mode=mode, page_size=8, speculative=2)
+    lens = np.array([3, 5], np.int32)
+    toks = np.array([[5, 9, 3, 0, 0], [7, 2, 8, 4, 1]], np.int32)
+    _, caches, tables = eng._run_prefill(toks, lens, 6)
+    before = _pool_hits()
+    eng._verify_chunk.lower(
+        eng.params, jnp.zeros((2,), jnp.int32), jnp.zeros((2, 2), jnp.int32),
+        caches, jnp.asarray(lens), jnp.full((2,), 6, jnp.int32),
+        jnp.full((2,), -1, jnp.int32), jnp.zeros((2,), bool),
+        jax.random.PRNGKey(0), tables, num_drafted=2, temperature=0.0,
+        top_k=0)
+    assert _pool_hits() > before
 
 
 # ---------------------------------------------------------------------------
