@@ -6,27 +6,72 @@ the configuration's family file (``bench/families/<family>.py``) makes.
 This module, the load loop and the family files are the benchmark's only
 importers of the program.  The engine's own tuning knobs
 (``decode_chunk``, ``prefill_chunk``) are left at the program's defaults.
+
+The deployment keys read here: ``cache_mode``, ``slots``, ``max_len``,
+``page_size`` and ``use_pallas``, always; ``mesh``, optional:
+``{"seq": N}`` splits each sequence over the cell's N chips (the
+program's sequence-sharded path, over a one-axis mesh ``("model",)``; N
+has to be the cell's ``chips``).  A mesh cell's weights are replicated,
+every chip holding the whole model (``weight_sharding``) and a 1/N share
+of each sequence.  A deployment without ``mesh`` builds the engine it
+always built.  ASTRA's attention stays off (``astra_mode="off"``): every
+cell serves at the precision its configuration's ``compute`` states.
 """
 from __future__ import annotations
 
-from typing import Dict, List, Tuple
+from typing import Dict, List, Optional, Sequence, Tuple
 
 import jax
+from jax.sharding import NamedSharding, PartitionSpec
 
+from repro.compat import make_mesh
 from repro.configs.base import ModelConfig
+from repro.core.sequence_parallel import MeshContext
 from repro.serving import steps as serving_steps
 from repro.serving.scheduler import ContinuousBatchingEngine
 
+SEQ_AXIS = "model"
 
-def make_engine(mcfg: ModelConfig, params, deployment: Dict,
-                seed: int) -> ContinuousBatchingEngine:
+
+def mesh_context(deployment: Dict, devices: Sequence) -> Optional[MeshContext]:
+    """The cell's mesh from ``deployment["mesh"]`` over ``devices`` (the
+    cell's chips), or None where the deployment names none.  A mesh whose
+    size is not the cell's chip count, or with a key other than
+    ``seq``, is refused."""
+    mesh = deployment.get("mesh")
+    if mesh is None:
+        return None
+    if set(mesh) != {"seq"}:
+        raise ValueError(f"a deployment's mesh names only 'seq'; got "
+                         f"{sorted(mesh)}")
+    n = int(mesh["seq"])
+    if n != len(devices):
+        raise ValueError(f"the deployment splits each sequence over {n} "
+                         f"chips; the cell has {len(devices)}")
+    return MeshContext(mesh=make_mesh((n,), (SEQ_AXIS,),
+                                      devices=list(devices)),
+                       batch_axes=(), seq_axis=SEQ_AXIS)
+
+
+def weight_sharding(ctx: Optional[MeshContext]):
+    """Every weight replicated over the mesh's chips, or None (no mesh:
+    the default device)."""
+    if ctx is None:
+        return None
+    return NamedSharding(ctx.mesh, PartitionSpec())
+
+
+def make_engine(mcfg: ModelConfig, params, deployment: Dict, seed: int,
+                mesh_ctx: Optional[MeshContext] = None
+                ) -> ContinuousBatchingEngine:
+    kw = {} if mesh_ctx is None else {"mesh_ctx": mesh_ctx}
     return ContinuousBatchingEngine(
         mcfg, params, slots=int(deployment["slots"]),
         max_len=int(deployment["max_len"]), astra_mode="off",
         cache_mode=deployment["cache_mode"],
         page_size=int(deployment["page_size"]),
         use_pallas=bool(deployment["use_pallas"]),
-        seed=seed & 0x7FFFFFFF)
+        seed=seed & 0x7FFFFFFF, **kw)
 
 
 def prefill_shapes(eng: ContinuousBatchingEngine,

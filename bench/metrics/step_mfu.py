@@ -1,5 +1,5 @@
 """Model step: model operations of the tokens the traced ticks processed
-over the ticks' summed host time at the chip's peak, %.
+over the ticks' summed host time at the peak of the cell's chips, %.
 
 Tokens are the real prompt tokens of prefill chunks and the decode
 tokens emitted (``work.token_flops``: 2 x the matmul parameters of every
@@ -20,4 +20,4 @@ def read(run):
     flops += sum(t.head_tokens for t in ticks) * 2 * s.d_model * s.vocab
     if not flops:
         return None
-    return 100.0 * flops / (secs * run.peaks["flops_per_s"])
+    return 100.0 * flops / (secs * run.peaks["flops_per_s"] * run.chips)

@@ -1,5 +1,6 @@
 """Plain float32 reference for dense pre-norm decoder LMs (GPT-2,
-StarCoder2), and the seeded weights both it and the served model use.
+StarCoder2, Qwen2), and the seeded weights both it and the served model
+use.
 
 Independent of the program: it takes a ``Spec`` read from the
 configuration file, makes the weights from the seed with its own
@@ -9,14 +10,22 @@ one layer at a time so that a model that fills the chip still fits once
 the served model's state is freed.  Every matmul runs at
 ``Precision.HIGHEST`` (float32 on the TPU's MXU).
 
-The block, as both families publish it (biases left out where the
+The block, as the families publish it (biases left out where the
 configuration file says the served model has none):
-  h = LN1(x); q, k, v = h Wq, h Wk, h Wv   (GQA: q head j reads kv head
-  j // (heads / kv_heads)); RoPE on q, k (rotate-half, theta from the
-  file) or learned absolute positions added to the embeddings;
+  h = N1(x); q, k, v = h Wq + bq, h Wk + bk, h Wv + bv   (biases only with
+  ``qkv_bias``; GQA: q head j reads kv head j // (heads / kv_heads)); RoPE
+  on q, k (rotate-half, theta from the file) or learned absolute
+  positions added to the embeddings;
   x += softmax(q k^T / sqrt(hd), causal) v Wo
-  x += gelu_tanh(LN2(x) W_up) W_down
-then LN_f and the head (tied: logits = LN_f(x) E^T; else LN_f(x) W_head).
+  x += gelu_tanh(N2(x) W_up) W_down, or with ``act="swiglu"``
+  x += (silu(N2(x) W_gate) * N2(x) W_up) W_down
+then N_f and the head (tied: logits = N_f(x) E^T; else N_f(x) W_head).
+N is LayerNorm (scale and bias) or, with ``norm="rmsnorm"``, RMSNorm
+(scale only).  Sequences longer than ``BLOCK_FROM`` positions attend in
+blocks of ``Q_BLOCK`` queries, each against every key with its row's
+softmax whole, and read the head in blocks of as many rows, so neither
+the score matrix nor the logits of a 32k sequence are ever live at once
+(under ``quant`` each block then has its own absmax scale).
 
 ``quant="fp8"`` is the control: the same forward with both operands of
 every matmul rounded to float8_e4m3fn under a per-tensor absmax scale.
@@ -34,8 +43,11 @@ import numpy as np
 
 HIGHEST = jax.lax.Precision.HIGHEST
 
-NORMS = ("layernorm",)
-ACTS = ("gelu_tanh",)
+NORMS = ("layernorm", "rmsnorm")
+ACTS = ("gelu_tanh", "swiglu")
+BLOCK_FROM = 4096  # longer sequences attend in query blocks
+Q_BLOCK = 512  # queries per block: 32 heads x 512 x 32k scores are 2.1 GB
+BIAS_SCALE = 0.5  # q/k/v biases ~ N(0, 0.5^2), against q/k/v of ~unit size
 
 
 @dataclasses.dataclass(frozen=True)
@@ -55,6 +67,7 @@ class Spec:
     norm: str = "layernorm"
     act: str = "gelu_tanh"
     tied: bool = True  # the head is the token embedding, transposed
+    qkv_bias: bool = False  # q, k and v projections carry a bias
 
     def __post_init__(self):
         if self.norm not in NORMS or self.act not in ACTS:
@@ -105,9 +118,12 @@ def embed_weights(key: jax.Array, s: Spec) -> Dict[str, jax.Array]:
 
 
 def layer_weights(key: jax.Array, s: Spec, layer) -> Dict[str, jax.Array]:
-    """Layer ``layer``'s weights (``layer`` may be traced)."""
-    k = jax.random.split(jax.random.fold_in(jax.random.fold_in(key, 1),
-                                            layer), 8)
+    """Layer ``layer``'s weights (``layer`` may be traced).  The gate and
+    the q/k/v biases come from keys folded in past the eight that every
+    spec draws, so a spec without them draws what it always drew.
+    Under ``norm="rmsnorm"`` the norms' biases are drawn and not used."""
+    kl = jax.random.fold_in(jax.random.fold_in(key, 1), layer)
+    k = jax.random.split(kl, 8)
     d, hd = s.d_model, s.head_dim
     w = {
         "wq": _normal(k[0], (d, s.heads * hd), d ** -0.5),
@@ -119,6 +135,14 @@ def layer_weights(key: jax.Array, s: Spec, layer) -> Dict[str, jax.Array]:
     }
     w["ln1_scale"], w["ln1_bias"] = _norm(k[6], d)
     w["ln2_scale"], w["ln2_bias"] = _norm(k[7], d)
+    if s.act == "swiglu":
+        w["w_gate"] = _normal(jax.random.fold_in(kl, 8), (d, s.ffn),
+                              d ** -0.5)
+    if s.qkv_bias:
+        kb = jax.random.split(jax.random.fold_in(kl, 9), 3)
+        w["bq"] = _normal(kb[0], (s.heads * hd,), BIAS_SCALE)
+        w["bk"] = _normal(kb[1], (s.kv_heads * hd,), BIAS_SCALE)
+        w["bv"] = _normal(kb[2], (s.kv_heads * hd,), BIAS_SCALE)
     return w
 
 
@@ -148,6 +172,17 @@ def _layernorm(x, scale, bias, eps):
     return (x - mu) / jnp.sqrt(var + eps) * scale + bias
 
 
+def _rmsnorm(x, scale, eps):
+    return x / jnp.sqrt(jnp.mean(jnp.square(x), -1, keepdims=True) + eps) \
+        * scale
+
+
+def _normed(x, scale, bias, s: Spec):
+    if s.norm == "rmsnorm":
+        return _rmsnorm(x, scale, s.eps)
+    return _layernorm(x, scale, bias, s.eps)
+
+
 def _rope(x, theta):
     """x: (T, H, hd), rotate-half RoPE at positions 0..T-1."""
     t, _, hd = x.shape
@@ -158,26 +193,57 @@ def _rope(x, theta):
     return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
 
 
-@functools.partial(jax.jit, static_argnames=("s", "quant"))
-def _layer(x, w, *, s: Spec, quant):
+def _block(t: int) -> Optional[int]:
+    """Rows a block of a sequence of ``t`` positions, or None: whole."""
+    return Q_BLOCK if t > BLOCK_FROM else None
+
+
+def _attend(q, k, v, quant, block: Optional[int] = None):
+    """Causal attention of q (T, H, hd) over k, v (T, H, hd): whole, or in
+    blocks of ``block`` queries (the last padded), each block's rows
+    against every key."""
+    t, _, hd = q.shape
+
+    def rows_out(qi, rows):
+        scores = _mm("thd,shd->hts", qi, k, quant) / math.sqrt(hd)
+        causal = jnp.arange(t)[None, :] <= rows[:, None]
+        p = jax.nn.softmax(jnp.where(causal, scores, -jnp.inf), axis=-1)
+        return _mm("hts,shd->thd", p, v, quant)
+
+    if block is None:
+        return rows_out(q, jnp.arange(t))
+    nb = -(-t // block)
+    qb = jnp.pad(q, ((0, nb * block - t), (0, 0), (0, 0)))
+    o = jax.lax.map(lambda a: rows_out(a[1], a[0] * block + jnp.arange(block)),
+                    (jnp.arange(nb), qb.reshape(nb, block, *q.shape[1:])))
+    return o.reshape(nb * block, *q.shape[1:])[:t]
+
+
+@functools.partial(jax.jit, static_argnames=("s", "quant", "block"))
+def _layer(x, w, *, s: Spec, quant, block: Optional[int] = None):
     t = x.shape[0]
     hd, rep = s.head_dim, s.heads // s.kv_heads
-    h = _layernorm(x, w["ln1_scale"], w["ln1_bias"], s.eps)
-    q = _mm("td,de->te", h, w["wq"], quant).reshape(t, s.heads, hd)
-    k = _mm("td,de->te", h, w["wk"], quant).reshape(t, s.kv_heads, hd)
-    v = _mm("td,de->te", h, w["wv"], quant).reshape(t, s.kv_heads, hd)
+    h = _normed(x, w["ln1_scale"], w["ln1_bias"], s)
+    q = _mm("td,de->te", h, w["wq"], quant)
+    k = _mm("td,de->te", h, w["wk"], quant)
+    v = _mm("td,de->te", h, w["wv"], quant)
+    if s.qkv_bias:
+        q, k, v = q + w["bq"], k + w["bk"], v + w["bv"]
+    q = q.reshape(t, s.heads, hd)
+    k = k.reshape(t, s.kv_heads, hd)
+    v = v.reshape(t, s.kv_heads, hd)
     if s.rope_theta:
         q, k = _rope(q, s.rope_theta), _rope(k, s.rope_theta)
     k, v = jnp.repeat(k, rep, axis=1), jnp.repeat(v, rep, axis=1)
-    scores = _mm("thd,shd->hts", q, k, quant) / math.sqrt(hd)
-    causal = jnp.tril(jnp.ones((t, t), bool))
-    p = jax.nn.softmax(jnp.where(causal, scores, -jnp.inf), axis=-1)
-    o = _mm("hts,shd->thd", p, v, quant).reshape(t, s.heads * hd)
+    o = _attend(q, k, v, quant, block).reshape(t, s.heads * hd)
     x = x + _mm("te,ed->td", o, w["wo"], quant)
-    h = _layernorm(x, w["ln2_scale"], w["ln2_bias"], s.eps)
+    h = _normed(x, w["ln2_scale"], w["ln2_bias"], s)
     u = _mm("td,df->tf", h, w["w_up"], quant)
-    g = 0.5 * u * (1.0 + jnp.tanh(math.sqrt(2.0 / math.pi)
-                                  * (u + 0.044715 * u ** 3)))
+    if s.act == "swiglu":
+        g = jax.nn.silu(_mm("td,df->tf", h, w["w_gate"], quant)) * u
+    else:
+        g = 0.5 * u * (1.0 + jnp.tanh(math.sqrt(2.0 / math.pi)
+                                      * (u + 0.044715 * u ** 3)))
     return x + _mm("tf,fd->td", g, w["w_down"], quant)
 
 
@@ -191,15 +257,30 @@ def _embed(tokens, e, *, s: Spec):
     return x
 
 
-@functools.partial(jax.jit, static_argnames=("s", "quant"))
-def _head_stats(x, e, targets, *, s: Spec, quant):
-    """Per position: the best logit, the logit of ``targets`` and the
-    argmax, from the head over the final hidden states."""
-    h = _layernorm(x, e["lnf_scale"], e["lnf_bias"], s.eps)
+def _head_rows(x, e, targets, s: Spec, quant):
+    h = _normed(x, e["lnf_scale"], e["lnf_bias"], s)
     lg = (_mm("td,vd->tv", h, e["embed"], quant) if s.tied
           else _mm("td,dv->tv", h, e["head"], quant))
     at = jnp.take_along_axis(lg, targets[:, None], axis=1)[:, 0]
     return jnp.max(lg, -1), at, jnp.argmax(lg, -1).astype(jnp.int32)
+
+
+@functools.partial(jax.jit, static_argnames=("s", "quant", "block"))
+def _head_stats(x, e, targets, *, s: Spec, quant,
+                block: Optional[int] = None):
+    """Per position: the best logit, the logit of ``targets`` and the
+    argmax, from the head over the final hidden states; with ``block``,
+    that many rows at a time, so the logits of a 32k sequence are never
+    live at once."""
+    if block is None:
+        return _head_rows(x, e, targets, s, quant)
+    t = x.shape[0]
+    nb = -(-t // block)
+    xb = jnp.pad(x, ((0, nb * block - t), (0, 0))).reshape(nb, block, -1)
+    tb = jnp.pad(targets, (0, nb * block - t)).reshape(nb, block)
+    out = jax.lax.map(lambda a: _head_rows(a[0], e, a[1], s, quant),
+                      (xb, tb))
+    return tuple(o.reshape(-1)[:t] for o in out)
 
 
 _embed_weights = jax.jit(embed_weights, static_argnums=(1,))
@@ -232,7 +313,8 @@ class Reference:
               for q in seqs]
         for layer in range(self.s.layers):
             w = _layer_weights(self.key, self.s, layer)
-            xs = [_layer(x, w, s=self.s, quant=quant) for x in xs]
+            xs = [_layer(x, w, s=self.s, quant=quant, block=_block(len(x)))
+                  for x in xs]
             del w
         return xs
 
@@ -242,5 +324,6 @@ class Reference:
         ``targets[i]`` is the token scored at position i (padded with 0)."""
         tg = np.zeros((x.shape[0],), np.int32)
         tg[: len(targets)] = targets
-        out = _head_stats(x, self.e, jnp.asarray(tg), s=self.s, quant=quant)
+        out = _head_stats(x, self.e, jnp.asarray(tg), s=self.s, quant=quant,
+                          block=_block(len(x)))
         return tuple(np.asarray(a) for a in out)

@@ -15,6 +15,8 @@ sample of finished requests are then checked against the configuration's
 plain reference (``bench/reference/<reference>.py``), and ``correct``
 says whether every compared number kept to its limit.
 
+A configuration whose deployment names a ``mesh`` is served over the
+cell's chips, the weights replicated on each (``bench/core/sut.py``).
 Needs an accelerator: with none, or fewer chips than the cell asks for,
 it exits non-zero and prints no result.  JAX's persistent compilation
 cache lives in ``.jax_cache/`` at the root of the checkout.
@@ -108,7 +110,7 @@ def _device_info(jax, chips: int, require_accelerator: bool):
     if len(devs) < chips:
         raise NoAccelerator(f"the cell needs {chips} chips, JAX found "
                             f"{len(devs)}")
-    return devs
+    return devs[:chips]
 
 
 def _peak_bytes(devs) -> int:
@@ -119,6 +121,8 @@ def _peak_bytes(devs) -> int:
 
 class Run:
     """What one run recorded, as the metric readers see it."""
+
+    chips = 1  # the cell's chips, whose peaks add up
 
     def __init__(self, **kw):
         self.__dict__.update(kw)
@@ -135,16 +139,18 @@ class Run:
 
 def run_cell(root: Path, workload: str, seed: int, seconds: float,
              trace: bool, *, require_accelerator: bool = True,
-             control: str = None) -> dict:
+             control: str = None, inspect=None) -> dict:
     """Run one cell and return the result line's object.  ``control``
     (calibration only) also reads the reference computed in that
-    precision over the same sample, under the key "control"."""
+    precision over the same sample, under the key "control".
+    ``inspect``, if given, is called with the run's ``Run`` once the
+    trace is read, before the metrics (``bench/layers.py``)."""
     import jax
 
     from bench.core import check as chk
     from bench.core import sut, work
     from bench.core.drive import LoadLoop
-    from bench.core.trace import Trace
+    from bench.core.scopes import ScopedTrace
     from bench.core.traffic import Traffic
 
     bench = json.loads((root / "BENCHMARK.json").read_text())
@@ -159,12 +165,14 @@ def run_cell(root: Path, workload: str, seed: int, seconds: float,
 
     ref, family, spec = load_model(cfg)
     deploy = cfg["deployment"]
+    mesh_ctx = sut.mesh_context(deploy, devs)
     mcfg = family.model_config(ccfg["name"], cfg, spec)
     t = time.perf_counter()
     params = jax.block_until_ready(family.make_params(
-        ref, spec, seed, cfg["compute"]["param_dtype"]))
+        ref, spec, seed, cfg["compute"]["param_dtype"],
+        sharding=sut.weight_sharding(mesh_ctx)))
     t_weights = time.perf_counter() - t
-    eng = sut.make_engine(mcfg, params, deploy, seed)
+    eng = sut.make_engine(mcfg, params, deploy, seed, mesh_ctx)
     traffic = Traffic(mix, seed, vocab=spec.vocab,
                       max_len=int(deploy["max_len"]))
     t = time.perf_counter()
@@ -214,7 +222,7 @@ def run_cell(root: Path, workload: str, seed: int, seconds: float,
 
     tr, tlo, thi = None, None, None
     if trace_dir is not None:
-        tr = Trace.from_dir(trace_dir)
+        tr = ScopedTrace.from_dir(trace_dir)
         shutil.rmtree(trace_dir, ignore_errors=True)
         spans = tr.spans("tick")
         if spans:
@@ -223,8 +231,10 @@ def run_cell(root: Path, workload: str, seed: int, seconds: float,
             tr = None
     run = Run(reqs=drv.reqs, ticks=drv.ticks, open=t_open, close=t_close,
               seconds=float(seconds), setup_s=setup_s, spec=spec, peaks=pk,
-              compiles_in_window=compiles_in_window, trace=tr,
-              trace_lo=tlo, trace_hi=thi, traced=traced)
+              chips=len(devs), compiles_in_window=compiles_in_window,
+              trace=tr, trace_lo=tlo, trace_hi=thi, traced=traced)
+    if inspect is not None:
+        inspect(run)
 
     kind = "per_layer" if trace else "end_to_end"
     metrics = {}

@@ -21,18 +21,29 @@ from bench.core import check as chk  # noqa: E402
 from bench.families import starcoder2  # noqa: E402
 from bench.reference import dense_lm  # noqa: E402
 
-LIMIT = 0.01  # tiny model: served gaps read <= 3e-4, the control >= 0.03
+# tiny model served in float32: sound runs read a gap of 0.0 whichever
+# requests finish in the window; the control reads 0.14, the planted
+# faults far more
+LIMIT = 0.01
 TINY = {
     "source": "test", "family": "starcoder2", "reference": "dense_lm",
     "num_hidden_layers": 2, "hidden_size": 64, "num_attention_heads": 4,
     "num_key_value_heads": 2, "intermediate_size": 128, "vocab_size": 512,
     "rope_theta": 10000.0, "norm_epsilon": 1e-6,
     "hidden_act": "gelu_pytorch_tanh", "tie_word_embeddings": False,
-    "compute": {"dtype": "bfloat16", "param_dtype": "float32"},
+    "compute": {"dtype": "float32", "param_dtype": "float32"},
     "deployment": {"cache_mode": "paged", "slots": 4, "max_len": 256,
                    "page_size": 16, "use_pallas": False},
     "check": {"max_logit_gap": LIMIT, "min_tokens": 40},
 }
+# the same model served in bfloat16, as the committed cells are, with a
+# limit of its own: a near-tie among logits of ~2.5 (one bfloat16 step
+# there is 0.0156) flips, so sound runs read up to 0.0256, more the more
+# requests finish in the window; the fp8 control, once 200 served tokens
+# are checked, reads 0.185 or more
+BF16_LIMIT = 0.08
+TINY_BF16 = dict(TINY, compute={"dtype": "bfloat16", "param_dtype": "float32"},
+                 check={"max_logit_gap": BF16_LIMIT, "min_tokens": 200})
 MIX = {"loop": "open", "rate_per_s": 20, "sizes": 32, "warm_s": 0.3,
        "schedule_seed": 3,
        "prompt": {"dist": "lognormal", "median": 40, "sigma": 0.8,
@@ -41,21 +52,26 @@ MIX = {"loop": "open", "rate_per_s": 20, "sizes": 32, "warm_s": 0.3,
                   "min": 4, "max": 40}}
 
 
-@pytest.fixture(scope="module")
-def root(tmp_path_factory):
-    d = tmp_path_factory.mktemp("bench_root")
+def make_root(d: Path, cfg: dict, chips: int = 1) -> Path:
+    """A checkout at ``d`` holding one cell, "tiny-chat": ``cfg`` under
+    ``MIX``, every metric reported in it."""
     (d / "bench" / "configs").mkdir(parents=True)
     (d / "bench" / "traffic").mkdir(parents=True)
-    (d / "bench" / "configs" / "tiny.json").write_text(json.dumps(TINY))
+    (d / "bench" / "configs" / "tiny.json").write_text(json.dumps(cfg))
     (d / "bench" / "traffic" / "tiny-open.json").write_text(json.dumps(MIX))
     bench = json.loads((ROOT / "BENCHMARK.json").read_text())
     bench["configs"] = [{"name": "tiny", "file": "bench/configs/tiny.json"}]
     bench["workloads"] = [{"name": "tiny-chat", "config": "tiny",
-                           "traffic": "tiny-open", "chips": 1}]
+                           "traffic": "tiny-open", "chips": chips}]
     for m in bench["end_to_end"] + bench["per_layer"]:
         m.pop("workloads", None)
     (d / "BENCHMARK.json").write_text(json.dumps(bench))
     return d
+
+
+@pytest.fixture(scope="module")
+def root(tmp_path_factory):
+    return make_root(tmp_path_factory.mktemp("bench_root"), TINY)
 
 
 def _run(root, seed, **kw):
@@ -74,6 +90,18 @@ def test_sound_run_is_correct_and_control_fails_the_limit(root):
     assert list(out["check"]) == list(out["check"])  # printed in order
     assert list(out)[-1] == "check"
     assert {"ttft_p50_ms", "setup_s"} <= set(out["metrics"])
+
+
+def test_bfloat16_run_is_correct_and_control_fails_its_limit(tmp_path):
+    out = br.run_cell(make_root(tmp_path, TINY_BF16), "tiny-chat",
+                      2 ** 31 + 45, 3.0, False, require_accelerator=False,
+                      control="fp8")
+    gap = out["check"]["max_logit_gap"]
+    assert gap["limit"] == BF16_LIMIT
+    assert out["correct"] is True, out["check"]
+    assert out["check"]["served_tokens_checked"]["value"] >= 150
+    assert out["control"]["max_logit_gap"] > BF16_LIMIT
+    assert out["control"]["correct"] is False
 
 
 def test_altered_token_reads_not_correct(root, monkeypatch):

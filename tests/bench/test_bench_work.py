@@ -112,3 +112,14 @@ def test_idle_share_by_hand():
     run = _run(2.5, [_Tick(0, 1, [1], [], 1)])
     run.trace_hi = 10.0
     assert _metric("device_idle_share").read(run) == pytest.approx(75.0)
+
+
+def test_mesh_cell_holds_work_against_all_its_chips():
+    """On a mesh of four chips the same work over the same (per-chip
+    averaged) time is a quarter of the chips' peaks together."""
+    ticks = [_Tick(0.0, 2.0, [3, 5], [1, 2], 2)]
+    for name in ("decode_attn_roofline", "step_mfu"):
+        one = _metric(name).read(_run(1.0, ticks))
+        run = _run(1.0, ticks)
+        run.chips = 4
+        assert _metric(name).read(run) == pytest.approx(one / 4)
