@@ -7,33 +7,61 @@ functions; a family that the program serves another way brings its own.
 
 The tree: LayerNorm norms carry ``scale`` and ``bias``, RMSNorm norms
 ``scale`` alone; a SwiGLU MLP carries ``w_gate`` beside ``w_up`` and
-``w_down``.  The program's dense path reads no q/k/v biases, so a spec
-with ``qkv_bias`` is refused (``model_config``) rather than served
-without them.
+``w_down``; with ``qkv_bias`` the attention carries ``bq`` (heads x
+head size), ``bk`` and ``bv`` (KV heads x head size) beside its four
+matrices.
+
+Every key of the spec that the reference reads reaches the program.  The
+q/k/v biases and the norms' epsilon go to the program's ``ModelConfig``
+as ``qkv_bias`` and ``norm_eps`` where it has those fields.  Where it
+lacks one, its dense path serves no biases, or ``apply_norm``'s default
+epsilon, and a spec that asks for anything else is refused
+(``model_config``) rather than served without it.
 """
 from __future__ import annotations
 
+import dataclasses
+import inspect
 from typing import Dict
 
 import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ASTRAConfig, ModelConfig
+from repro.models.layers import apply_norm
 
 # the reference's activation -> the program's name for it (the program's
 # "gelu" is jax.nn.gelu(approximate=True), the tanh form)
 _ACT = {"gelu_tanh": "gelu", "swiglu": "swiglu"}
 
+# per key the program's ModelConfig may lack: what its dense path then
+# serves (no q/k/v biases; apply_norm's default epsilon), and what it is
+_FIXED = {
+    "qkv_bias": (False, "q/k/v biases"),
+    "norm_eps": (inspect.signature(apply_norm).parameters["eps"].default,
+                 "norm epsilon"),
+}
+
 
 def model_config(name: str, cfg: Dict, spec) -> ModelConfig:
     """The program's config for the file's model, as run: no ASTRA
     codebooks (the cell serves the fp paged cache), activations in
-    ``compute.dtype``, parameters in ``compute.param_dtype``; norm,
-    activation and head tying as the spec read them from the file.  A
-    spec with q/k/v biases is refused: the program has none to read."""
-    if spec.qkv_bias:
-        raise ValueError(f"{name}: the program's dense path reads no q/k/v "
-                         "biases, so it cannot serve this model")
+    ``compute.dtype``, parameters in ``compute.param_dtype``; norm, its
+    epsilon, activation, head tying and q/k/v biases as the spec read
+    them from the file.  A spec that the program cannot be told (a key
+    its ``ModelConfig`` lacks, with a value other than what the program
+    fixes) is refused."""
+    fields = {f.name for f in dataclasses.fields(ModelConfig)}
+    extra = {}
+    for key, value in (("qkv_bias", spec.qkv_bias), ("norm_eps", spec.eps)):
+        fixed, what = _FIXED[key]
+        if key in fields:
+            extra[key] = value
+        elif value != fixed:
+            raise ValueError(
+                f"{name}: the file's {what} ({value!r}) cannot reach the "
+                f"program: its ModelConfig has no {key!r} and its dense "
+                f"path serves {fixed!r}")
     compute = cfg["compute"]
     return ModelConfig(
         name=name, arch_type="dense", num_layers=spec.layers,
@@ -43,7 +71,7 @@ def model_config(name: str, cfg: Dict, spec) -> ModelConfig:
         activation=_ACT[spec.act], tie_embeddings=spec.tied,
         astra=ASTRAConfig(enabled=False),
         dtype=compute["dtype"], param_dtype=compute["param_dtype"],
-        max_seq_len=spec.positions or cfg["deployment"]["max_len"])
+        max_seq_len=spec.positions or cfg["deployment"]["max_len"], **extra)
 
 
 def _norm(spec, scale, bias):
@@ -66,13 +94,15 @@ def params_builder(ref, spec, param_dtype: str, sharding=None):
 
         def one(layer):
             w = ref.layer_weights(key, spec, layer)
+            attn = {k: w[k] for k in ("wq", "wk", "wv", "wo")}
+            if spec.qkv_bias:
+                attn.update(bq=w["bq"], bk=w["bk"], bv=w["bv"])
             mlp = {"w_up": w["w_up"], "w_down": w["w_down"]}
             if spec.act == "swiglu":
                 mlp["w_gate"] = w["w_gate"]
             return jax.tree.map(lambda a: a.astype(dt), {
                 "norm1": _norm(spec, w["ln1_scale"], w["ln1_bias"]),
-                "attn": {"wq": w["wq"], "wk": w["wk"], "wv": w["wv"],
-                         "wo": w["wo"]},
+                "attn": attn,
                 "norm2": _norm(spec, w["ln2_scale"], w["ln2_bias"]),
                 "mlp": mlp,
             })

@@ -6,9 +6,12 @@ Qwen2 is RMSNorm, RoPE, grouped-query attention with biases on the q, k
 and v projections (none on the output projection or the MLP), and a
 gated MLP by its architecture; ``hidden_act`` and
 ``tie_word_embeddings`` are read from the file.  A sliding window is not
-modelled: a file that turns it on is refused.  The reference computes
-the q/k/v biases; the program's dense path reads none yet, so
-``model_config`` refuses a Qwen2 file until it does.
+modelled: a file that turns it on is refused.  The q/k/v biases and
+``rms_norm_eps`` go to the program as ``_dense`` maps them: the biases
+as ``bq``/``bk``/``bv`` in each layer's attention, both as keys of the
+program's ``ModelConfig``.  While the program's dense path reads no
+biases (its ``ModelConfig`` has no ``qkv_bias``), ``model_config``
+refuses a Qwen2 file rather than serve it without them.
 """
 from __future__ import annotations
 

@@ -53,12 +53,23 @@ import json, sys
 from pathlib import Path
 root, tests = Path(sys.argv[1]), Path(sys.argv[2])
 sys.path[:0] = [str(tests), str(tests.parents[1]), str(tests.parents[1] / "src")]
+import dataclasses
 import jax
 import test_bench_check as tbc
+import test_bench_reference as tbr
 import bench.run as br
 from bench.core import sut
 
-cfg = getattr(tbc, sys.argv[3])
+cfg = getattr(tbc, sys.argv[3], None) or getattr(tbr, sys.argv[3])
+if sys.argv[5] == "no_qkv_bias":
+    # the q/k/v biases out of the spec, the reference's and the program's
+    load_model = br.load_model
+
+    def without_biases(c):
+        ref, family, spec = load_model(c)
+        return ref, family, dataclasses.replace(spec, qkv_bias=False)
+
+    br.load_model = without_biases
 cfg = dict(cfg, deployment=dict(cfg["deployment"], mesh={"seq": 4}))
 tbc.make_root(root, cfg, chips=4)
 
@@ -85,16 +96,21 @@ print(json.dumps({"out": out, "seen": seen}))
 
 # float32 reads the reference's gap of 0; bfloat16, the cells' dtype, is
 # held to its own limit (on 4 CPU devices sound runs read up to 0.0217
-# over 18 seeds, against test_bench_check's BF16_LIMIT of 0.08)
-@pytest.mark.parametrize("cfg,seconds", [("TINY", 1.5), ("TINY_BF16", 3.0)],
-                         ids=["float32", "bfloat16"])
-def test_mesh_cell_serves_sharded_and_reads_correct(tmp_path, cfg, seconds):
+# over 18 seeds, against test_bench_check's BF16_LIMIT of 0.08).  The
+# Qwen2-shaped file (RMSNorm, SwiGLU, GQA, untied head) serves in float32
+# with its q/k/v biases out of the spec, as on one device
+# (test_bench_reference): the program reads none yet.
+@pytest.mark.parametrize("cfg,seconds,spec", [
+    ("TINY", 1.5, "as_read"), ("TINY_BF16", 3.0, "as_read"),
+    ("TINY_QWEN", 1.5, "no_qkv_bias")], ids=["float32", "bfloat16", "qwen2"])
+def test_mesh_cell_serves_sharded_and_reads_correct(tmp_path, cfg, seconds,
+                                                   spec):
     env = dict(os.environ, JAX_PLATFORMS="cpu",
                XLA_FLAGS="--xla_force_host_platform_device_count=4")
     env.pop("PYTHONPATH", None)
     proc = subprocess.run(
         [sys.executable, "-c", _SCRIPT, str(tmp_path / "root"),
-         str(Path(__file__).resolve().parent), cfg, str(seconds)],
+         str(Path(__file__).resolve().parent), cfg, str(seconds), spec],
         capture_output=True, text=True, timeout=120, env=env, cwd=tmp_path)
     assert proc.returncode == 0, proc.stderr[-4000:]
     got = json.loads(proc.stdout.strip().splitlines()[-1])

@@ -1,14 +1,21 @@
 """The plain reference: the weights and hidden states of every spec it
 modelled before RMSNorm, SwiGLU and q/k/v biases came in stay as they
 were, bit for bit; long sequences attend in query blocks that agree with
-whole attention; and a Qwen2-shaped configuration (RMSNorm, SwiGLU)
-runs through the harness, while one with q/k/v biases, which the program
-does not read, is refused."""
+whole attention.  The dense family: every key the reference reads
+(q/k/v biases and the norm epsilon among them) reaches a program whose
+``ModelConfig`` takes it, and a file that asks for one the program
+cannot take is refused; the bias leaves stand in each layer's attention
+tree; the committed configurations map to the program config and tree
+they always had; a Qwen2-shaped configuration (RMSNorm, SwiGLU) runs
+through the harness, and one whose served model leaves out the biases
+that the reference adds reads not correct."""
 import dataclasses
+import json
 import sys
 from pathlib import Path
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -18,8 +25,10 @@ sys.path.insert(0, str(ROOT / "src"))
 sys.path.insert(0, str(Path(__file__).resolve().parent))
 
 import bench.run as br  # noqa: E402
-from bench.families import qwen2  # noqa: E402
+from bench.families import _dense, qwen2  # noqa: E402
 from bench.reference import dense_lm  # noqa: E402
+from repro.configs.base import ASTRAConfig, ModelConfig  # noqa: E402
+from repro.models.transformer import init_lm  # noqa: E402
 from test_bench_check import make_root  # noqa: E402
 
 SEED = 2 ** 33 + 7
@@ -140,24 +149,124 @@ TINY_QWEN = {
 }
 
 
-def test_family_maps_qwen2_keys_and_tree():
-    spec = dense_lm.Spec(**qwen2.spec(TINY_QWEN))
-    assert (spec.norm, spec.act, spec.qkv_bias, spec.tied) == \
-        ("rmsnorm", "swiglu", True, False)
+def _program(*extra):
+    """A stand-in for the program's ``ModelConfig``: the fields that
+    ``_dense.model_config`` always passes, and ``extra``."""
+    return dataclasses.make_dataclass("ModelConfig", [
+        "name", "arch_type", "num_layers", "d_model", "num_heads",
+        "num_kv_heads", "d_ff", "vocab_size", "rope_theta", "norm",
+        "activation", "tie_embeddings", "astra", "dtype", "param_dtype",
+        "max_seq_len", *extra], frozen=True)
+
+
+def test_family_maps_qwen2_keys_and_tree(monkeypatch):
+    cfg = dict(TINY_QWEN, rms_norm_eps=1e-5)
+    spec = dense_lm.Spec(**qwen2.spec(cfg))
+    assert (spec.norm, spec.act, spec.qkv_bias, spec.tied, spec.eps) == \
+        ("rmsnorm", "swiglu", True, False, 1e-5)
+    # a program whose ModelConfig takes both keys is told both
+    monkeypatch.setattr(_dense, "ModelConfig",
+                        _program("qkv_bias", "norm_eps"))
+    mcfg = qwen2.model_config("tiny", cfg, spec)
+    assert mcfg.qkv_bias is True
+    assert mcfg.norm_eps == cfg["rms_norm_eps"]
+    assert (mcfg.norm, mcfg.activation, mcfg.tie_embeddings) == \
+        ("rmsnorm", "swiglu", False)
+    # one that lacks them serves no biases and a fixed epsilon of 1e-6:
+    # the file is refused unless it asks for just that
+    monkeypatch.setattr(_dense, "ModelConfig", _program())
     with pytest.raises(ValueError, match="q/k/v biases"):
-        qwen2.model_config("tiny", TINY_QWEN, spec)  # the program has none
-    spec = dataclasses.replace(spec, qkv_bias=False)
-    mcfg = qwen2.model_config("tiny", TINY_QWEN, spec)
+        qwen2.model_config("tiny", cfg, spec)
+    with pytest.raises(ValueError, match="norm epsilon"):
+        qwen2.model_config("tiny", cfg,
+                           dataclasses.replace(spec, qkv_bias=False))
+    plain = dataclasses.replace(spec, qkv_bias=False, eps=1e-6)
+    mcfg = qwen2.model_config("tiny", TINY_QWEN, plain)
     assert (mcfg.norm, mcfg.activation) == ("rmsnorm", "swiglu")
+    monkeypatch.undo()
+
     params = qwen2.make_params(dense_lm, spec, SEED, "float32")
     layer = params["stages"][0]["sub0"]
     assert set(layer["norm1"]) == {"scale"}
     assert set(layer["mlp"]) == {"w_gate", "w_up", "w_down"}
-    assert set(layer["attn"]) == {"wq", "wk", "wv", "wo"}
+    assert set(layer["attn"]) == {"wq", "wk", "wv", "wo", "bq", "bk", "bv"}
     w = dense_lm.layer_weights(dense_lm.base_key(SEED), spec, 1)
+    for k, heads in (("bq", spec.heads), ("bk", spec.kv_heads),
+                     ("bv", spec.kv_heads)):
+        assert layer["attn"][k].shape == (spec.layers,
+                                          heads * spec.head_dim)
+        assert layer["attn"][k].dtype == jnp.float32
+        np.testing.assert_array_equal(layer["attn"][k][1], w[k])
     np.testing.assert_array_equal(layer["mlp"]["w_gate"][1], w["w_gate"])
+    # without biases the tree is the one the program itself makes
+    bare = qwen2.make_params(dense_lm, plain, SEED, "float32")
+    assert set(bare["stages"][0]["sub0"]["attn"]) == {"wq", "wk", "wv", "wo"}
+    _assert_program_tree(bare, qwen2.model_config("tiny", TINY_QWEN, plain),
+                         "float32")
     with pytest.raises(ValueError):
         qwen2.spec(dict(TINY_QWEN, use_sliding_window=True))
+
+
+def _assert_program_tree(params, mcfg, param_dtype):
+    """``params`` (arrays or shapes) has the structure, shapes and dtypes
+    of the tree that the program's own ``init_lm`` makes for ``mcfg``."""
+    want = jax.eval_shape(lambda k: init_lm(k, mcfg, jnp.dtype(param_dtype)),
+                          jax.random.PRNGKey(0))
+    assert jax.tree.structure(params) == jax.tree.structure(want)
+    for got, exp in zip(jax.tree.leaves(params), jax.tree.leaves(want)):
+        assert (got.shape, got.dtype) == (exp.shape, exp.dtype)
+
+
+# the program config each committed configuration mapped to before the
+# biases and the epsilon were mapped
+COMMITTED = {
+    "gpt2-small": dict(num_layers=12, d_model=768, num_heads=12,
+                       num_kv_heads=12, d_ff=3072, vocab_size=50257,
+                       rope_theta=0.0, max_seq_len=1024),
+    "starcoder2-3b": dict(num_layers=20, d_model=3072, num_heads=24,
+                          num_kv_heads=2, d_ff=12288, vocab_size=49152,
+                          rope_theta=999999.4420358813, max_seq_len=4096),
+}
+
+
+@pytest.mark.parametrize("name", sorted(COMMITTED))
+def test_committed_configs_keep_their_program_and_tree(name, monkeypatch):
+    cfg = json.loads((ROOT / "bench" / "configs" / f"{name}.json")
+                     .read_text())
+    ref, family, spec = br.load_model(cfg)
+    assert (spec.qkv_bias, spec.eps) == (False, 1e-6)
+    mcfg = family.model_config(name, cfg, spec)
+    assert mcfg == ModelConfig(
+        name=name, arch_type="dense", norm="layernorm", activation="gelu",
+        tie_embeddings=True, astra=ASTRAConfig(enabled=False),
+        dtype="bfloat16", param_dtype="float32", **COMMITTED[name])
+    shapes = jax.eval_shape(family.params_builder(ref, spec, "float32"),
+                            ref.base_key(SEED))
+    _assert_program_tree(shapes, mcfg, "float32")
+    # a program that takes both keys is told what it serves without them
+    monkeypatch.setattr(_dense, "ModelConfig",
+                        _program("qkv_bias", "norm_eps"))
+    mcfg = family.model_config(name, cfg, spec)
+    assert (mcfg.qkv_bias, mcfg.norm_eps) == (False, 1e-6)
+
+
+def test_qwen2_file_is_served_whole_or_refused(tmp_path):
+    """A Qwen2 file with biases and a published epsilon of 1e-5: where
+    the program's ModelConfig takes both keys the harness serves it and
+    it reads correct; where it lacks one, the harness refuses the file
+    before anything is served."""
+    cfg = dict(TINY_QWEN, rms_norm_eps=1e-5)
+    root = make_root(tmp_path, cfg)
+    fields = {f.name for f in dataclasses.fields(ModelConfig)}
+    if not {"qkv_bias", "norm_eps"} <= fields:
+        with pytest.raises(ValueError, match="cannot reach the program"):
+            br.run_cell(root, "tiny-chat", 2 ** 31 + 35, 1.5, False,
+                        require_accelerator=False)
+        return
+    out = br.run_cell(root, "tiny-chat", 2 ** 31 + 35, 1.5, False,
+                      require_accelerator=False)
+    assert out["check"]["requests_checked"]["value"] >= 1
+    assert out["correct"] is True, out["check"]
 
 
 def test_qwen_shaped_config_runs_through_the_harness(tmp_path, monkeypatch):
@@ -177,3 +286,36 @@ def test_qwen_shaped_config_runs_through_the_harness(tmp_path, monkeypatch):
     assert check["requests_checked"]["value"] >= 1
     assert check["wrong_length"]["value"] == 0
     assert out["correct"] is True, check
+
+
+def test_qwen_biases_left_out_of_the_served_model_read_not_correct(
+        tmp_path, monkeypatch):
+    """The reference adds the q/k/v biases; the served model is made and
+    configured without them (its bias leaves dropped from the tree): the
+    check reads not correct."""
+    load_model = br.load_model
+
+    def program_without_biases(cfg):
+        ref, family, spec = load_model(cfg)
+        assert spec.qkv_bias
+        plain = dataclasses.replace(spec, qkv_bias=False)
+
+        class Family:
+            @staticmethod
+            def model_config(name, cfg, _):
+                return family.model_config(name, cfg, plain)
+
+            @staticmethod
+            def make_params(ref, _, *args, **kw):
+                return family.make_params(ref, plain, *args, **kw)
+
+        return ref, Family, spec
+
+    monkeypatch.setattr(br, "load_model", program_without_biases)
+    out = br.run_cell(make_root(tmp_path, TINY_QWEN), "tiny-chat",
+                      2 ** 31 + 33, 1.5, False, require_accelerator=False)
+    check = out["check"]
+    assert check["requests_checked"]["value"] >= 1
+    assert check["max_logit_gap"]["value"] > 10 * check["max_logit_gap"][
+        "limit"], check
+    assert out["correct"] is False
